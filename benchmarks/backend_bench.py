@@ -21,10 +21,10 @@ assert the single-launch property (`megabatch.dispatches == 1`,
 `megabatch.compiles == 1`) and track the perf trajectory as an
 artifact.
 
-CLI (CI runs the smoke variant):
+CLI (CI runs the smoke variant; its timings are XLA-CPU numbers):
 
-  PYTHONPATH=src python -m benchmarks.backend_bench
-  PYTHONPATH=src python -m benchmarks.backend_bench --smoke
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.backend_bench
+  JAX_PLATFORMS=cpu PYTHONPATH=src python -m benchmarks.backend_bench --smoke
 """
 from __future__ import annotations
 
@@ -36,11 +36,14 @@ import sys
 import time
 from typing import Optional, Tuple
 
-# one XLA host device per core, so the jax backend's batches shard
-# across cores like the NumPy pool's workers do; must be set before JAX
-# initializes (the runner imports it lazily, on first use)
+# on the CPU platform (JAX_PLATFORMS=cpu), one XLA host device per core,
+# so the jax backend's batches shard across cores like the NumPy pool's
+# workers do; must be set before JAX initializes (the runner imports it
+# lazily, on first use).  On an accelerator the flag would only split
+# the host, so it stays unset there.
 _flags = os.environ.get("XLA_FLAGS", "")
-if "xla_force_host_platform_device_count" not in _flags:
+if (os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu"
+        and "xla_force_host_platform_device_count" not in _flags):
     os.environ["XLA_FLAGS"] = (
         f"{_flags} --xla_force_host_platform_device_count="
         f"{os.cpu_count() or 1}").strip()

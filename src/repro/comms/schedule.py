@@ -67,14 +67,17 @@ def resolve_model(ss):
 def grad_chunk_bytes(cfg, n_planes: int) -> np.ndarray:
     """Dtype-aware gradient micro-chunk sizes for the whole model —
     `stream_report` over the `jax.eval_shape` parameter pytree, i.e. the
-    exact chunking the plane-sharded allreduce engine would stream."""
+    exact chunking the plane-sharded allreduce engine would stream.
+    The key is abstract too, so no JAX backend starts here: schedule
+    compiles run in NumPy pool workers and beside a process that holds
+    the accelerator."""
     import jax
     import jax.numpy as jnp
     from repro.core.collectives import stream_report
     from repro.core.planes import PlaneConfig
     from repro.models.transformer import init_params
-    tree = jax.eval_shape(lambda k: init_params(k, cfg),
-                          jax.random.PRNGKey(0))
+    key = jax.ShapeDtypeStruct((2,), jnp.uint32)    # a raw PRNGKey
+    tree = jax.eval_shape(lambda k: init_params(k, cfg), key)
     # Pin every leaf to param_dtype: gradients stream at master-weight
     # precision, and byte volumes must not depend on whether the host
     # process enabled x64 (init leaves widen to f64 there).

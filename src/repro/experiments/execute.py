@@ -15,10 +15,14 @@ JAX paths — which is what lets `run_experiment` write the cache and
 fill the `ResultSet` incrementally instead of all-or-nothing at the
 end.
 
-`enable_compile_cache` points JAX's persistent compilation cache at a
-directory, so the megabatch program (one compile per grid *structure*)
-survives process restarts; `scenario_sweep --compile-cache-dir` wires
-it up and reports entry counts next to the run-cache stats.
+Every JAX sweep keeps JAX's persistent compilation cache on
+(`enable_compile_cache`), so the megabatch program (one compile per
+grid *structure*) survives process restarts: in `JAX_COMPILATION_CACHE_DIR`
+when that is set, else in an explicit `compile_cache_dir`, else in
+`.jax_cache/` at the root of the checkout.
+
+NumPy pool workers pin JAX to the CPU before any backend starts, so a
+worker never reaches for an accelerator the parent process holds.
 """
 from __future__ import annotations
 
@@ -40,18 +44,31 @@ OnResult = Callable[[int, ScenarioMetrics], None]
 
 JX_DISPATCH_MODES = ("megabatch", "group")
 
+# the compile cache's home when neither JAX_COMPILATION_CACHE_DIR nor an
+# explicit directory names one: fixed (the path is part of every cache
+# key, so a moving directory never hits) and git-ignored
+DEFAULT_COMPILE_CACHE_DIR = os.path.abspath(os.path.join(
+    os.path.dirname(__file__), "..", "..", "..", ".jax_cache"))
 
-def enable_compile_cache(cache_dir: str) -> None:
-    """Enable JAX's persistent compilation cache at `cache_dir` (created
-    if missing) with thresholds dropped to zero so every simulator
-    program is cached — a re-run of a sweep in a fresh process then pays
-    deserialization instead of XLA compilation."""
+
+def enable_compile_cache(cache_dir: Optional[str] = None) -> str:
+    """Enable JAX's persistent compilation cache with thresholds dropped
+    to zero, so every simulator program is cached — a re-run of a sweep
+    in a fresh process then pays deserialization instead of XLA
+    compilation.  `JAX_COMPILATION_CACHE_DIR`, when set, is the cache
+    and wins over `cache_dir`; otherwise `cache_dir`, else
+    `DEFAULT_COMPILE_CACHE_DIR`.  Returns the directory (created if
+    missing)."""
     import jax
 
+    cache_dir = os.path.abspath(
+        os.environ.get("JAX_COMPILATION_CACHE_DIR") or cache_dir
+        or DEFAULT_COMPILE_CACHE_DIR)
     os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", str(cache_dir))
+    jax.config.update("jax_compilation_cache_dir", cache_dir)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return cache_dir
 
 
 def compile_cache_entries(cache_dir: str) -> int:
@@ -62,6 +79,17 @@ def compile_cache_entries(cache_dir: str) -> int:
                    if n.endswith("-cache"))
     except OSError:
         return 0
+
+
+def _pin_cpu() -> None:
+    """NumPy pool initializer: keep this worker's JAX (schedule compiles
+    and `derive` callbacks may touch it) on the CPU platform, set before
+    any backend starts.  An accelerator belongs to one process, and the
+    parent may hold it."""
+    os.environ["JAX_PLATFORMS"] = "cpu"
+    import jax
+
+    jax.config.update("jax_platforms", "cpu")
 
 
 def _timed_point(p: ScenarioSpec, derive: Optional[Callable] = None):
@@ -88,7 +116,8 @@ def execute_points(points: List[ScenarioSpec],
     it completes, *before* the call returns.  `jx_dispatch` picks the
     JAX dispatch path ('megabatch' default, 'group' = the legacy
     per-structure batching; `REPRO_JX_DISPATCH` overrides the default);
-    `compile_cache_dir` enables the persistent XLA compilation cache.
+    `compile_cache_dir` places the persistent compilation cache unless
+    `JAX_COMPILATION_CACHE_DIR` is set (see `enable_compile_cache`).
 
     `flight`, when a dict, is filled with the executor flight-recorder
     summary: backend/mode, total wall clock, per-point wall times (JAX
@@ -114,8 +143,7 @@ def execute_points(points: List[ScenarioSpec],
                 "backend= explicitly")
         backend = inherited.pop() if inherited else "numpy"
     if backend == "jax":
-        if compile_cache_dir:
-            enable_compile_cache(compile_cache_dir)
+        enable_compile_cache(compile_cache_dir)
         mode = (jx_dispatch or
                 os.environ.get("REPRO_JX_DISPATCH", "megabatch"))
         if mode not in JX_DISPATCH_MODES:
@@ -172,7 +200,8 @@ def execute_points(points: List[ScenarioSpec],
     else:
         ctx = multiprocessing.get_context()
     out: List[Optional[ScenarioMetrics]] = [None] * len(points)
-    with ProcessPoolExecutor(max_workers=processes, mp_context=ctx) as ex:
+    with ProcessPoolExecutor(max_workers=processes, mp_context=ctx,
+                             initializer=_pin_cpu) as ex:
         futures = {ex.submit(runner, p): i for i, p in enumerate(points)}
         pending = set(futures)
         while pending:
@@ -191,13 +220,16 @@ def _xla_backend_live() -> bool:
     """True iff an XLA backend (and its thread pools) was plausibly
     created in this process — not merely `import jax`.  First line: our
     own jax engine's dispatch flag (set on actual use, not import).
-    Second line: jax's backend cache (private, so probed defensively —
-    if jax renames it we degrade to the first check)."""
+    Second line: jax's private backend cache,
+    `jax._src.xla_bridge._backends`, filled once a backend starts
+    (pinned by `tests/test_placement.py`, so a JAX upgrade that
+    renames it fails a test instead of silently forking a live
+    backend)."""
     if getattr(sys.modules.get("repro.netsim.jx.engine"),
                "_BACKEND_USED", False):
         return True
     xb = sys.modules.get("jax._src.xla_bridge")
-    return bool(getattr(xb, "_backends", None))
+    return xb is not None and bool(xb._backends)
 
 
 def _execute_jax(points: List[ScenarioSpec], derive: Optional[Callable],
@@ -257,7 +289,6 @@ def _execute_jax(points: List[ScenarioSpec], derive: Optional[Callable],
                                                    plan_megabatch)
 
             import jax
-            from jax.experimental import disable_x64, enable_x64
 
             compiled = [compile_scenario(p) for p in points]
             caches, planned = plan_megabatch(compiled)
@@ -267,11 +298,10 @@ def _execute_jax(points: List[ScenarioSpec], derive: Optional[Callable],
             def prep(group):
                 # the worker thread runs outside the main thread's
                 # collect_dispatch scope AND its thread-local jax
-                # config overrides (`enable_x64()` contexts): adopt the
-                # counters and re-assert the caller's x64 state so the
+                # config overrides (`jax.enable_x64(...)` contexts): adopt
+                # the counters and re-assert the caller's x64 state so the
                 # launch traces with the caller's dtypes
-                with adopt_dispatch(collectors), \
-                        (enable_x64() if x64 else disable_x64()):
+                with adopt_dispatch(collectors), jax.enable_x64(x64):
                     return dispatch_planned(group, caches)
 
             launches = 0

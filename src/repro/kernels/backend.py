@@ -9,10 +9,15 @@ is TPU, unless `REPRO_NETSIM_PALLAS` forces it (1/0).
 """
 from __future__ import annotations
 
+import math
 import os
-from typing import Optional
+from typing import List, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
+
+LANES = 128
+SUBLANES = 8
 
 
 def pallas_enabled(override: Optional[bool] = None) -> bool:
@@ -34,3 +39,28 @@ def pallas_interpret(override: Optional[bool] = None) -> bool:
     if override is not None:
         return override
     return jax.default_backend() != "tpu"
+
+
+def to_lanes(arrays: Sequence[jax.Array], br: int
+             ) -> Tuple[List[jax.Array], int, int]:
+    """Lay same-size arrays out for an elementwise kernel as float32
+    `(rows, 128)` slabs, zero-padded so `rows` is a multiple of the
+    block rows.  Returns `(slabs, block_rows, n_blocks)`; block rows
+    are `br` (a multiple of 8), shrunk to the slab for small inputs, so
+    every block is (8, 128)-tile aligned as Mosaic requires."""
+    if br % SUBLANES:
+        raise ValueError(f"block rows {br} are not a multiple of "
+                         f"{SUBLANES}")
+    n = arrays[0].size
+    rows = -(-max(n, 1) // LANES)
+    br = min(br, -(-rows // SUBLANES) * SUBLANES)
+    rows = -(-rows // br) * br
+    pad = rows * LANES - n
+    slabs = [jnp.pad(a.reshape(-1).astype(jnp.float32), (0, pad))
+             .reshape(rows, LANES) for a in arrays]
+    return slabs, br, rows // br
+
+
+def from_lanes(slab: jax.Array, shape, dtype) -> jax.Array:
+    """Inverse of `to_lanes` for one output slab."""
+    return slab.reshape(-1)[:math.prod(shape)].reshape(shape).astype(dtype)

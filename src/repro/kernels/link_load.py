@@ -105,37 +105,27 @@ def _bottleneck_kernel(cap_ref, load_ref, out_ref, *, eps: float):
 
 
 def bottleneck(cap: jax.Array, load: jax.Array, *, eps: float = EPS,
-               bp: int = 1024, use_pallas: bool = False,
+               br: int = 256, use_pallas: bool = False,
                interpret: Optional[bool] = None) -> jax.Array:
-    """Elementwise min(1, cap/load) scale factor, any matching shape."""
+    """Elementwise min(1, cap/load) scale factor, any matching shape.
+    The Pallas path runs the flattened links as `(br, 128)` blocks
+    (`backend.to_lanes`)."""
     from . import backend, ref
 
     if not use_pallas:
         return ref.bottleneck_ref(cap, load, eps=eps)
-    shape = cap.shape
-    n = cap.size
-    bp = min(bp, max(n, 1))
-    pad = (-n) % bp
-    cap2 = cap.reshape(-1)
-    load2 = load.reshape(-1)
-    if pad:
-        cap2 = jnp.pad(cap2, (0, pad))
-        load2 = jnp.pad(load2, (0, pad))
-    n_blk = cap2.shape[0] // bp
+    slabs, br, n_blk = backend.to_lanes((cap, load), br)
+    spec = pl.BlockSpec((br, backend.LANES), lambda i: (i, 0))
     kernel = functools.partial(_bottleneck_kernel, eps=eps)
     out = pl.pallas_call(
         kernel,
         grid=(n_blk,),
-        in_specs=[
-            pl.BlockSpec((1, bp), lambda i: (i, 0)),
-            pl.BlockSpec((1, bp), lambda i: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bp), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_blk, bp), jnp.float32),
+        in_specs=[spec, spec],
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct(slabs[0].shape, jnp.float32),
         interpret=backend.pallas_interpret(interpret),
-    )(cap2.reshape(n_blk, bp).astype(jnp.float32),
-      load2.reshape(n_blk, bp).astype(jnp.float32))
-    return out.reshape(-1)[:n].reshape(shape).astype(cap.dtype)
+    )(*slabs)
+    return backend.from_lanes(out, cap.shape, cap.dtype)
 
 
 def segment_load(vals: jax.Array, keys: jax.Array,

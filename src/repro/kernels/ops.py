@@ -104,19 +104,19 @@ def bucket_load_bottleneck(g, cap, *, eps: float = _ll.EPS,
                                       interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "bp"))
-def bottleneck(cap, load, *, eps: float = _ll.EPS, bp: int = 1024):
+@functools.partial(jax.jit, static_argnames=("eps", "br"))
+def bottleneck(cap, load, *, eps: float = _ll.EPS, br: int = 256):
     """Elementwise min(1, cap/load) link scale factor (Pallas path)."""
-    return _ll.bottleneck(cap, load, eps=eps, bp=bp, use_pallas=True,
+    return _ll.bottleneck(cap, load, eps=eps, br=br, use_pallas=True,
                           interpret=_interpret())
 
 
-@functools.partial(jax.jit, static_argnames=("q_cap", "eps", "bp"))
+@functools.partial(jax.jit, static_argnames=("q_cap", "eps", "br"))
 def queue_update(q, load, cap, *, q_cap: float, eps: float = _qe.EPS,
-                 bp: int = 1024):
+                 br: int = 256):
     """Fluid queue integrator + utilization (Pallas path; see
     `queue_ecn.queue_update` for the dispatching entry point)."""
-    return _qe.queue_update(q, load, cap, q_cap=q_cap, eps=eps, bp=bp,
+    return _qe.queue_update(q, load, cap, q_cap=q_cap, eps=eps, br=br,
                             use_pallas=True, interpret=_interpret())
 
 

@@ -47,23 +47,28 @@ def _plb_kernel(rate_ref, elig_ref, queue_ref, tx_ref, hash_ref, out_ref,
 def _plane_split_kernel(rate_ref, elig_ref, demand_ref, out_ref,
                         *, mode: str, n_planes: int, min_rate: float):
     """One block of flows: fluid plane split for a static NIC `mode`
-    (see `ref.plane_split_ref`).  Pure VPU work on (bp, P) tiles."""
+    (see `ref.plane_split_ref`).  Pure VPU work on (bp, P) tiles.
+
+    Masks stay 0/1 float32 throughout: Mosaic cannot reduce or
+    re-materialise i1 vectors ("Unsupported target bitwidth for
+    truncation"), and sums of exact 0/1 floats equal the oracle's
+    integer counts bit for bit."""
     rate = rate_ref[...].astype(jnp.float32)             # (bp, P)
-    elig = elig_ref[...] > 0
+    elig = elig_ref[...].astype(jnp.float32)             # 0/1 mask
     demand = demand_ref[...].astype(jnp.float32)         # (bp, 1)
     if mode == "dcqcn":
         out = jnp.minimum(demand * (1.0 / n_planes), rate)
     elif mode == "swlb":
-        n_up = jnp.maximum(jnp.sum(elig, axis=1, keepdims=True), 1)
-        out = jnp.where(elig, demand / n_up, 0.0)
+        n_up = jnp.maximum(jnp.sum(elig, axis=1, keepdims=True), 1.0)
+        out = jnp.where(elig > 0, demand / n_up, 0.0)
     elif mode == "agg":
-        n_up = jnp.maximum(jnp.sum(elig, axis=1, keepdims=True), 1)
+        n_up = jnp.maximum(jnp.sum(elig, axis=1, keepdims=True), 1.0)
         shared = jnp.min(rate, axis=1, keepdims=True)
-        out = jnp.where(elig, demand * shared / n_up, 0.0)
+        out = jnp.where(elig > 0, demand * shared / n_up, 0.0)
     else:  # spx: rate filter (E2E precedence) then allowance weighting
-        ok = elig & (rate > min_rate + 1e-9)
-        any_ok = jnp.any(ok, axis=1, keepdims=True)
-        ok = jnp.where(any_ok, ok, elig)
+        ok = jnp.where(rate > min_rate + 1e-9, elig, 0.0)
+        any_ok = jnp.max(ok, axis=1, keepdims=True)
+        ok = jnp.where(any_ok > 0, ok, elig) > 0
         w = jnp.where(ok, rate, 0.0)
         s = jnp.sum(w, axis=1, keepdims=True)
         w = jnp.where(s > 0, w / jnp.maximum(s, 1e-12), 1.0 / n_planes)

@@ -40,34 +40,30 @@ def _queue_update_kernel(q_ref, load_ref, cap_ref, qn_ref, util_ref,
 
 
 def queue_update(q: jax.Array, load: jax.Array, cap: jax.Array, *,
-                 q_cap: float, eps: float = EPS, bp: int = 1024,
+                 q_cap: float, eps: float = EPS, br: int = 256,
                  use_pallas: bool = False,
                  interpret: Optional[bool] = None):
-    """One slot of fluid queue evolution.  Returns `(q_new, util)`."""
+    """One slot of fluid queue evolution.  Returns `(q_new, util)`.
+    The Pallas path runs the flattened links as `(br, 128)` blocks
+    (`backend.to_lanes`); zero-capacity pad links stay finite."""
     from . import backend, ref
 
     if not use_pallas:
         return ref.queue_update_ref(q, load, cap, q_cap=q_cap, eps=eps)
-    shape = q.shape
-    n = q.size
-    bp = min(bp, max(n, 1))
-    pad = (-n) % bp
-    flat = [a.reshape(-1) for a in (q, load, cap)]
-    if pad:
-        flat = [jnp.pad(a, (0, pad)) for a in flat]
-    n_blk = flat[0].shape[0] // bp
+    slabs, br, n_blk = backend.to_lanes((q, load, cap), br)
+    spec = pl.BlockSpec((br, backend.LANES), lambda i: (i, 0))
     kernel = functools.partial(_queue_update_kernel, q_cap=q_cap,
                                eps=eps)
     qn, util = pl.pallas_call(
         kernel,
         grid=(n_blk,),
-        in_specs=[pl.BlockSpec((1, bp), lambda i: (i, 0))] * 3,
-        out_specs=[pl.BlockSpec((1, bp), lambda i: (i, 0))] * 2,
-        out_shape=[jax.ShapeDtypeStruct((n_blk, bp), jnp.float32)] * 2,
+        in_specs=[spec] * 3,
+        out_specs=[spec] * 2,
+        out_shape=[jax.ShapeDtypeStruct(slabs[0].shape, jnp.float32)] * 2,
         interpret=backend.pallas_interpret(interpret),
-    )(*(a.reshape(n_blk, bp).astype(jnp.float32) for a in flat))
-    return (qn.reshape(-1)[:n].reshape(shape).astype(q.dtype),
-            util.reshape(-1)[:n].reshape(shape).astype(q.dtype))
+    )(*slabs)
+    return (backend.from_lanes(qn, q.shape, q.dtype),
+            backend.from_lanes(util, q.shape, q.dtype))
 
 
 def _nic_update_kernel(qmean_ref, rate_ref, alpha_ref, esr_ref,

@@ -11,7 +11,7 @@ inside `scan` — only `FaultSpec`-declared schedules are supported, not
 arbitrary event closures.
 
 Parity: with x64 enabled (`JAX_ENABLE_X64=1` or
-`jax.experimental.enable_x64()`), results match the NumPy backend within
+`with jax.enable_x64(True):`), results match the NumPy backend within
 1e-5 on every registry scenario (see `tests/test_jx_parity.py`).
 """
 from .events import FaultTimeline, compile_fault_timeline, has_static_timeline

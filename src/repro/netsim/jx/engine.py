@@ -660,6 +660,36 @@ def _host_sum(cfg: JxConfig, vals: jnp.ndarray, idx: jnp.ndarray,
         cfg.n_hosts, P)
 
 
+def _path_sum(x: jnp.ndarray) -> jnp.ndarray:
+    """Sum over the last (path) axis.  In float64 (parity mode) the adds
+    follow NumPy's pairwise order for a contiguous axis — eight strided
+    accumulators combined as a tree, then the tail in sequence, halves
+    above 128 — because these sums scale whole transfers: a path-weighted
+    scale that lands one ulp under 1.0 leaves a residue in `remaining`
+    and finishes an exact-size transfer a slot late.  Float32 runs keep
+    XLA's own reduction."""
+    n = x.shape[-1]
+    if x.dtype != jnp.float64:
+        return x.sum(-1)
+    if n > 128:
+        h = n // 2
+        h -= h % 8
+        return _path_sum(x[..., :h]) + _path_sum(x[..., h:])
+    if n < 8:
+        out = x[..., 0]
+        for i in range(1, n):
+            out = out + x[..., i]
+        return out
+    m = n - n % 8
+    r = [x[..., j] for j in range(8)]
+    for i in range(8, m, 8):
+        r = [r[j] + x[..., i + j] for j in range(8)]
+    out = ((r[0] + r[1]) + (r[2] + r[3])) + ((r[4] + r[5]) + (r[6] + r[7]))
+    for i in range(m, n):
+        out = out + x[..., i]
+    return out
+
+
 def _pair_rate_sum(cfg: JxConfig, fabric_rate: jnp.ndarray,
                    pair_idx: jnp.ndarray,
                    aggs: "_AggPerms") -> jnp.ndarray:
@@ -697,11 +727,11 @@ def _route_pair(cfg: JxConfig, carry: SimCarry, fabric_rate: jnp.ndarray,
     scale_pair = jnp.minimum(
         f_up[:, :, None, :],
         f_down.transpose(0, 2, 1)[:, None, :, :])         # (P, L, L, S)
-    path_scale = (pair * scale_pair).sum(-1).reshape(P, L * L)
+    path_scale = _path_sum(pair * scale_pair).reshape(P, L * L)
     through = fabric_rate * path_scale[:, pair_idx].T
     q_pair = (carry.q_up[:, :, None, :] +
               carry.q_down.transpose(0, 2, 1)[:, None, :, :])
-    qmean = (pair * q_pair).sum(-1).reshape(P, L * L)[:, pair_idx].T
+    qmean = _path_sum(pair * q_pair).reshape(P, L * L)[:, pair_idx].T
     if not cfg.react:
         return load_up, load_down, through, qmean
     # blackholed bytes: offered rate steered (by the lagged view) onto
@@ -831,9 +861,9 @@ def _route_pair_ft(cfg: JxConfig, carry: SimCarry,
     sB = jnp.minimum(fB_up[:, pol, :][:, :, None, :],
                      fB_dn[:, pol, :][:, None, :, :])
     scale_pair = jnp.where(cross, jnp.minimum(sA, sB), sA)
-    path_scale = (pair * scale_pair).sum(-1).reshape(P, L * L)
+    path_scale = _path_sum(pair * scale_pair).reshape(P, L * L)
     through = fabric_rate * path_scale[:, pair_idx].T
-    qmean = (pair * q).sum(-1).reshape(P, L * L)[:, pair_idx].T
+    qmean = _path_sum(pair * q).reshape(P, L * L)[:, pair_idx].T
     if not cfg.react:
         return loadA_up, loadA_dn, loadB_up, loadB_dn, through, qmean
     # physical per-pair path capacity (the visible `cap` above steered
@@ -1211,11 +1241,11 @@ def _jitted_mb(cfg: JxConfig, n_shards: int = 1,
     (routing, nic) via traced `StackIdx`, with the initial scan carry
     donated — the step rewrites it wholesale, so XLA reuses its buffers
     instead of allocating a second batch.  With `n_shards > 1` the
-    batch axis is `jax.sharding`-partitioned over a 1-D "lane" device
-    mesh (`lane_mesh`): operands arrive flat `(B, ...)`, `in_shardings`
-    places them, and the per-shard computation stays device-local via a
-    shard-axis `vmap` — the modern replacement for the old device-major
-    `pmap` layout, structured to extend to `jax.distributed` meshes.
+    batch axis is split over a 1-D "lane" device mesh (`lane_mesh`):
+    operands arrive flat `(B, ...)` and `shard_map` hands each device
+    its own contiguous block — the replacement for the old
+    device-major `pmap` layout, structured to extend to
+    `jax.distributed` meshes.
 
     `lanes` is the dispatcher's static per-shard layout: a tuple of
     `(route_index, n_elements)` runs.  Elements are lane-sorted by the
@@ -1256,36 +1286,19 @@ def _jitted_mb(cfg: JxConfig, n_shards: int = 1,
     if n_shards == 1:
         fn = jax.jit(body, donate_argnums=(1,))
     else:
-        from jax.sharding import NamedSharding, PartitionSpec
+        from jax.sharding import PartitionSpec
 
-        mesh = lane_mesh(n_shards)
-        lane = NamedSharding(mesh, PartitionSpec("lane"))
-        repl = NamedSharding(mesh, PartitionSpec())
-        tm = jax.tree_util.tree_map
-
-        def sharded(*args):
-            # flat (B, ...) operands -> (shards, per, ...) so the lanes
-            # body (whose run lengths are per-shard) vmaps over the
-            # shard axis with device-local data; outputs flatten back.
-            # The reshape splits the already-lane-sharded leading axis
-            # evenly, so no resharding happens at either end.
-            mapped, table = args[:-1], args[-1]
-            per = np.shape(jax.tree_util.tree_leaves(
-                mapped[0])[0])[0] // n_shards
-
-            def split(x):
-                return jnp.reshape(
-                    x, (n_shards, per) + tuple(x.shape[1:]))
-
-            out = jax.vmap(body, in_axes=(0,) * 17 + (None,))(
-                *tm(split, mapped), table)
-            return tm(lambda x: jnp.reshape(
-                x, (n_shards * per,) + tuple(x.shape[2:])), out)
-
+        # flat (B, ...) operands split evenly over the "lane" axis; each
+        # device runs the lanes body (whose run lengths are per-shard)
+        # on its own block.  `shard_map` rather than GSPMD partitioning:
+        # Mosaic kernels cannot be partitioned automatically.  The body
+        # has no collectives, so nothing needs the varying-axes check
+        # (which Pallas output shapes and scan carries do not carry).
+        lane = PartitionSpec("lane")
         fn = jax.jit(
-            sharded,
-            in_shardings=(lane,) * 17 + (repl,),
-            out_shardings=lane,
+            jax.shard_map(body, mesh=lane_mesh(n_shards),
+                          in_specs=(lane,) * 17 + (PartitionSpec(),),
+                          out_specs=lane, check_vma=False),
             donate_argnums=(1,))
     _JIT_CACHE[key] = fn
     return fn

@@ -261,8 +261,12 @@ def _carry0(B: int, F_b: int, cfg: JxConfig,
         util_up=np.zeros((B, P, L, U), fdt))
 
 
-def _dispatch_group(cfg: JxConfig, pts: List[_Point], caches: Dict):
-    """Assemble one structural group into a single launch.
+def _assemble_group(cfg: JxConfig, pts: List[_Point], caches: Dict,
+                    n_devices: Optional[int] = None):
+    """Assemble one structural group into the operands of a single
+    launch.  Returns `(shards, lanes, args, metas)`: the shard count,
+    the static lane layout, the program's operands, and the
+    `(point_index, flow_arrays)` of every batch row.
 
     Elements are **lane-sorted** by routing branch: within a lane the
     `StackIdx.route` index is a concrete constant, so the engine traces
@@ -347,7 +351,7 @@ def _dispatch_group(cfg: JxConfig, pts: List[_Point], caches: Dict):
                 "caps": padded, "dem": dem,
                 "stack": stack_idx_for(p.routing, p.nic)}
 
-    n_dev = len(jax.devices())
+    n_dev = n_devices or len(jax.devices())
     shards = min(len(pts), n_dev) if n_dev > 1 and len(pts) > 1 else 1
 
     # lane-sort: per route, pad the lane to a multiple of the shard
@@ -401,17 +405,24 @@ def _dispatch_group(cfg: JxConfig, pts: List[_Point], caches: Dict):
     # multi-shard groups stay flat (B, ...): the mesh-sharded program
     # reshapes to (shards, B//shards, ...) internally, and `seq` is
     # already dealt device-major so the flat order is shard-major
-    engine._record_launch("mega", (cfg, shards, lanes_static),
-                          mapped + (table,))
+    metas = [(e["index"], e["fa"]) for e in seq]
+    return shards, lanes_static, mapped + (table,), metas
+
+
+def _dispatch_group(cfg: JxConfig, pts: List[_Point], caches: Dict,
+                    n_devices: Optional[int] = None):
+    """Launch one structural group as a single program (see
+    `_assemble_group`)."""
+    shards, lanes, args, metas = _assemble_group(cfg, pts, caches,
+                                                 n_devices)
+    engine._record_launch("mega", (cfg, shards, lanes), args)
     with warnings.catch_warnings():
         # the scan rewrites the whole donated carry, but only 4 of its
         # leaves alias a program output — jax warns about the rest on
         # every first compile, which is expected here, not actionable
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable")
-        out = engine._jitted_mb(cfg, shards, lanes_static)(*mapped,
-                                                           table)
-    metas = [(e["index"], e["fa"]) for e in seq]
+        out = engine._jitted_mb(cfg, shards, lanes)(*args)
     return cfg, metas, [p.index for p in pts], shards, out
 
 
@@ -435,12 +446,11 @@ def plan_megabatch(points: List) -> Tuple[Dict, List[List[Tuple]]]:
     return caches, [groups[k] for k in order]
 
 
-def dispatch_planned(group: List[Tuple], caches: Dict) -> List:
-    """Full host prep + launch for one planned group.  Runs the
-    memoized `_prepare` for each member, sub-splits by the complete
+def _sub_groups(group: List[Tuple], caches: Dict
+                ) -> List[Tuple[JxConfig, List[_Point]]]:
+    """Memoized `_prepare` of every member, sub-split by the complete
     structural key (fault-timeline segment counts only become known
-    here), and launches each sub-group.  Returns `[(point_indices,
-    handle)]` entries for `finalize_group`."""
+    here)."""
     prepared = [_prepare(i, c, caches) for i, c in group]
     sub: Dict[Tuple, List[_Point]] = {}
     order: List[Tuple] = []
@@ -451,15 +461,38 @@ def dispatch_planned(group: List[Tuple], caches: Dict) -> List:
             sub[key] = []
             order.append(key)
         sub[key].append(p)
+    return [(key[0], sub[key]) for key in order]
+
+
+def dispatch_planned(group: List[Tuple], caches: Dict,
+                     n_devices: Optional[int] = None) -> List:
+    """Full host prep + launch for one planned group: one launch per
+    complete structural key.  `n_devices` caps the lane mesh (default:
+    every visible device).  Returns `[(point_indices, handle)]` entries
+    for `finalize_group`."""
+    return [([p.index for p in pts],
+             _dispatch_group(cfg, pts, caches, n_devices))
+            for cfg, pts in _sub_groups(group, caches)]
+
+
+def megabatch_programs(points: List, n_devices: Optional[int] = None
+                       ) -> List[Tuple]:
+    """The `(jitted program, operands)` that `dispatch_megabatch` would
+    launch for these `CompiledScenario`s, without launching anything —
+    for inspecting a program (`fn.lower(*args).compile()`), or compiling
+    it for a device described by shapes alone."""
+    caches, planned = plan_megabatch(points)
     out = []
-    for key in order:
-        pts = sub[key]
-        handle = _dispatch_group(key[0], pts, caches)
-        out.append(([p.index for p in pts], handle))
+    for group in planned:
+        for cfg, pts in _sub_groups(group, caches):
+            shards, lanes, args, _ = _assemble_group(cfg, pts, caches,
+                                                     n_devices)
+            out.append((engine._jitted_mb(cfg, shards, lanes), args))
     return out
 
 
-def dispatch_megabatch(points: List) -> List:
+def dispatch_megabatch(points: List,
+                       n_devices: Optional[int] = None) -> List:
     """Group `CompiledScenario`s by structural key and launch each group
     as ONE fused program (all groups dispatched before any is awaited —
     JAX CPU execution is async).  Returns `[(point_indices, handle)]`
@@ -470,7 +503,7 @@ def dispatch_megabatch(points: List) -> List:
     caches, planned = plan_megabatch(points)
     out: List = []
     for group in planned:
-        out.extend(dispatch_planned(group, caches))
+        out.extend(dispatch_planned(group, caches, n_devices))
     return out
 
 
@@ -502,12 +535,14 @@ def finalize_group(handle) -> List[JxSimResult]:
     return [by_index[i] for i in order]
 
 
-def run_megabatch(points: List) -> List[JxSimResult]:
+def run_megabatch(points: List, n_devices: Optional[int] = None
+                  ) -> List[JxSimResult]:
     """Simulate arbitrary `CompiledScenario` grid points with the fewest
     possible launches (one per structural group), returning results in
-    point order."""
+    point order.  `n_devices` caps the lane mesh (default: every visible
+    device) — e.g. 1 to rerun a sharded grid on a single device."""
     results: List = [None] * len(points)
-    for idxs, handle in dispatch_megabatch(points):
+    for idxs, handle in dispatch_megabatch(points, n_devices):
         for i, r in zip(idxs, finalize_group(handle)):
             results[i] = r
     return results

@@ -8,7 +8,7 @@ import jax  # noqa: E402
 # x64 stays off for tier-1 (model/kernel tests expect f32); the CI
 # jax-backend job exports JAX_ENABLE_X64=1 for the parity/golden suites.
 # Backend-parity tests additionally scope x64 via
-# jax.experimental.enable_x64, so they hold under either default.
+# `with jax.enable_x64(True):`, so they hold under either default.
 jax.config.update(
     "jax_enable_x64",
     os.environ.get("JAX_ENABLE_X64", "0").lower() in ("1", "true", "t",

@@ -21,9 +21,9 @@ Pins the schedule compiler end to end:
 import math
 import types
 
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.comms import plan_schedule, sim_bytes
 from repro.comms.lower import lower_schedule
@@ -282,7 +282,7 @@ def test_flap_moe_signature():
 
 def test_schedule_backend_parity():
     spec = get_scenario("train_step_flap")
-    with enable_x64():
+    with jax.enable_x64(True):
         c = compile_scenario(spec)
         ref = c.run(backend="numpy")
         jres = c.run(backend="jax")

@@ -15,9 +15,9 @@ dispatch path, and the chunk-size-independence of delivered bytes.
 import os
 import warnings
 
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 try:
     from hypothesis import given, settings
@@ -68,7 +68,7 @@ def test_chunked_bit_identical_x64(kind, chunk):
     """The tentpole contract: every chunk length — singleton, a
     non-divisible 17, longer-than-F, and exactly F — reproduces the
     monolithic sparse engine bit for bit at x64."""
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = get_scenario(SCN[kind]).with_sim(slots=48, backend="jax")
         mono = _run_chunk(spec, 0)
         chunked = _run_chunk(spec, chunk)
@@ -80,7 +80,7 @@ def test_chunked_bit_identical_x64_routings(routing):
     """Every routing branch has its own chunked transcription (pair
     tables vs per-stage ECMP fractions) — pin each at the awkward
     non-divisible chunk length."""
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = get_scenario("fig8_bisection").with_sim(
             slots=48, routing=routing, nic="dcqcn", backend="jax")
         mono = _run_chunk(spec, 0)
@@ -96,7 +96,7 @@ if HAVE_HYPOTHESIS:
         """The named invariant: total delivered bytes (flow count x the
         mean per-flow goodput integral the engine reports) do not depend
         on how the flow axis was chunked."""
-        with enable_x64():
+        with jax.enable_x64(True):
             spec = get_scenario(SCN[kind]).with_sim(slots=36,
                                                     backend="jax")
             mono = _run_chunk(spec, 0)
@@ -131,7 +131,7 @@ def test_chunked_megabatch_row_identity_x64():
                      Axis("seed", (0, 1)),
                      Axis("sim.slots", (80,))))
     points = [p.spec for p in exp.points()]
-    with enable_x64():
+    with jax.enable_x64(True):
         mono = execute_points(points, backend="jax",
                               jx_dispatch="megabatch")
         prev = os.environ.get("REPRO_JX_FLOW_CHUNK")

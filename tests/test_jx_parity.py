@@ -14,9 +14,9 @@ Fast cross-product cases run in tier-1; the full-length all-registry
 sweep and the batched-sweep equivalence run under `-m slow` (the CI
 jax-backend job includes them).
 """
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.netsim.jx import compile_fault_timeline, has_static_timeline
 from repro.scenarios import (SweepGrid, compile_scenario, distill_metrics,
@@ -26,7 +26,7 @@ TOL = 1e-5
 
 
 def _run_both(spec):
-    with enable_x64():
+    with jax.enable_x64(True):
         ref = compile_scenario(spec).run(backend="numpy")
         jres = compile_scenario(spec).run(backend="jax")
     return ref, jres
@@ -246,7 +246,7 @@ def test_parity_full_registry_cross(name, routing, nic):
 @pytest.mark.parametrize("routing", ["ar", "ecmp"])
 def test_parity_batched_sweep_matches_serial(routing):
     grid = SweepGrid(seeds=(0, 1, 2), routings=(routing,), slots=150)
-    with enable_x64():
+    with jax.enable_x64(True):
         serial = sweep("fig9_victim_noise", grid, processes=1)
         batched = sweep("fig9_victim_noise", grid, backend="jax")
     assert [m.to_row() for m in serial] == [m.to_row() for m in batched]

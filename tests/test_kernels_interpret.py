@@ -115,7 +115,7 @@ def test_bottleneck_interpret_odd_shape():
     rng = RNG(5)
     cap = jnp.asarray(_f64(rng, 2, 5, 7, lo=0.1))
     load = jnp.asarray(_f64(rng, 2, 5, 7, hi=2.0))
-    got = link_load.bottleneck(cap, load, bp=16, use_pallas=True,
+    got = link_load.bottleneck(cap, load, br=8, use_pallas=True,
                                interpret=True)
     want = ref.bottleneck_ref(cap, load, eps=link_load.EPS)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
@@ -130,7 +130,7 @@ def test_queue_update_interpret():
     cap = jnp.asarray(_f64(rng, 2, 8, 8))
     cap = cap.at[0, 0, 0].set(0.0)                  # dead link
     got_q, got_u = queue_ecn.queue_update(
-        q, load, cap, q_cap=16.0, bp=16, use_pallas=True, interpret=True)
+        q, load, cap, q_cap=16.0, br=8, use_pallas=True, interpret=True)
     want_q, want_u = ref.queue_update_ref(q, load, cap, q_cap=16.0)
     np.testing.assert_allclose(np.asarray(got_q), np.asarray(want_q),
                                rtol=1e-5, atol=1e-6)
@@ -177,3 +177,51 @@ def test_engine_pallas_interpret_smoke(monkeypatch):
     got = run_point(spec).mean_goodput
     assert np.isfinite(got) and got > 0
     assert got == pytest.approx(base, rel=0.05)
+
+
+@pytest.mark.parametrize("shape,br", [((3, 37, 29), 8), ((2, 5, 7), 256),
+                                      ((4096,), 8), ((9, 1000), 16)])
+def test_lane_kernels_bit_identical(shape, br):
+    """`queue_update` and `bottleneck` run on (rows, 128) lane slabs in
+    (br, 128) blocks; multi-block grids, zero-padded row tails and
+    sub-tile inputs all reproduce the float32 oracles bit for bit."""
+    rng = RNG(8)
+    q = jnp.asarray(_f64(rng, *shape, hi=4.0), jnp.float32)
+    load = jnp.asarray(_f64(rng, *shape, hi=2.0), jnp.float32)
+    cap = jnp.asarray(_f64(rng, *shape), jnp.float32)
+    cap = cap.reshape(-1).at[::7].set(0.0).reshape(shape)   # dead links
+    got_q, got_u = queue_ecn.queue_update(
+        q, load, cap, q_cap=16.0, br=br, use_pallas=True, interpret=True)
+    want_q, want_u = ref.queue_update_ref(q, load, cap, q_cap=16.0)
+    np.testing.assert_array_equal(np.asarray(got_q), np.asarray(want_q))
+    np.testing.assert_array_equal(np.asarray(got_u), np.asarray(want_u))
+    got_f = link_load.bottleneck(cap, load, br=br, use_pallas=True,
+                                 interpret=True)
+    want_f = ref.bottleneck_ref(cap, load, eps=link_load.EPS)
+    np.testing.assert_array_equal(np.asarray(got_f), np.asarray(want_f))
+    assert got_q.shape == got_f.shape == shape
+
+
+def test_lane_layout_rejects_unaligned_block_rows():
+    with pytest.raises(ValueError, match="multiple of 8"):
+        link_load.bottleneck(jnp.ones(4), jnp.ones(4), br=12,
+                             use_pallas=True, interpret=True)
+
+
+@pytest.mark.parametrize("mode", ["spx", "dcqcn", "agg", "swlb"])
+def test_plane_split_bit_identical(mode):
+    """The float32-mask kernel body reproduces the bool-mask oracle bit
+    for bit, including all-ineligible rows and the spx fallback to the
+    eligible set when every plane sits below `min_rate`."""
+    rng = RNG(9)
+    F, P = 300, 4
+    rate = rng.uniform(0.0, 1.0, (F, P)).astype(np.float32)
+    rate[::5] = 0.01                                # spx fallback rows
+    elig = rng.uniform(size=(F, P)) > 0.3
+    elig[::11] = False                              # nothing eligible
+    demand = rng.uniform(size=F).astype(np.float32)
+    args = (jnp.asarray(rate), jnp.asarray(elig), jnp.asarray(demand))
+    got = plb_select.plane_split(*args, mode=mode, min_rate=0.05, bp=64,
+                                 use_pallas=True, interpret=True)
+    want = ref.plane_split_ref(*args, mode=mode, min_rate=0.05)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

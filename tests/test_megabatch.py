@@ -14,9 +14,9 @@ selectors and stacks whole grids into one `jit(vmap)` launch
   * the `_jitted` device-fingerprint regression (a pmap built for N
     devices must not be reused when the device set changes).
 """
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.experiments import Axis, Experiment, execute_points, product
 from repro.netsim.jx import dispatch_stats, reset_dispatch_stats
@@ -33,7 +33,7 @@ def _grid_points(base, axes):
 
 
 def _run_both(points):
-    with enable_x64():
+    with jax.enable_x64(True):
         group = execute_points(points, backend="jax",
                                jx_dispatch="group")
         mega = execute_points(points, backend="jax",
@@ -149,13 +149,13 @@ def test_megabatch_mixed_topology_kinds_one_compile_per_bucket():
         Axis("sim.slots", (200,)),      # random_fail at 150 still fires
     ])
     reset_dispatch_stats()
-    with enable_x64():
+    with jax.enable_x64(True):
         mega = execute_points(points, backend="jax",
                               jx_dispatch="megabatch")
     stats = dispatch_stats()
     assert stats["dispatches"] == 2, stats   # one per topology kind
     assert stats["compiles"] == 2, stats
-    with enable_x64():
+    with jax.enable_x64(True):
         group = execute_points(points, backend="jax", jx_dispatch="group")
     _assert_rows_identical(points, group, mega)
 

@@ -291,10 +291,11 @@ def test_unknown_backend_rejected():
 
 
 def test_backend_field_dispatches_jax():
-    from jax.experimental import enable_x64
     spec = get_scenario("fig12_plane_flap").with_sim(slots=80,
                                                      backend="jax")
-    with enable_x64():   # f32 trajectories may fork at CC thresholds
+    import jax
+
+    with jax.enable_x64(True):   # f32 trajectories may fork at CC thresholds
         m = run_point(spec)
         ref = run_point(spec.with_sim(backend="numpy"))
     assert m.mean_goodput == pytest.approx(ref.mean_goodput, abs=1e-5)

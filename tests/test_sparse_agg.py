@@ -9,9 +9,9 @@ routing mode.  Hypothesis drives the shapes/seeds.
 """
 import os
 
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 try:
     from hypothesis import given, settings
@@ -60,7 +60,7 @@ def _assert_close(a, b, rtol, path=""):
 
 
 def _check_sparse_matches_dense(kind, routing, nic, seed):
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = get_scenario(SCN[kind]).with_sim(
             slots=40, routing=routing, nic=nic, seed=seed,
             backend="jax")
@@ -90,7 +90,7 @@ def test_sparse_matches_numpy_engine_x64():
     """Under x64 the sparse segment-sum is flow-ordered like the numpy
     engine's `np.add.at`, so it must hit the full cross-backend parity
     tolerance too — not just agree with the dense jax path."""
-    with enable_x64():
+    with jax.enable_x64(True):
         spec = get_scenario("fig12_plane_flap").with_sim(
             slots=40, routing="war", nic="dcqcn", backend="jax")
         sparse = _run_agg(spec, "sparse")
@@ -126,7 +126,7 @@ def test_f32_carry_drift_vs_f64_bounded():
     spec = get_scenario("fig12_plane_flap").with_sim(
         slots=60, routing="war", nic="dcqcn", backend="jax")
     f32 = run_point(spec)
-    with enable_x64():
+    with jax.enable_x64(True):
         f64 = run_point(spec)
     assert f32.mean_goodput == pytest.approx(f64.mean_goodput, rel=1e-3)
     assert f32.isolation_index == pytest.approx(f64.isolation_index,

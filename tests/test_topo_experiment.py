@@ -4,9 +4,9 @@ x fault-frac through the megabatch path with numpy↔jax row parity at
 1e-5 (x64), and the multiplane fabric shows strictly higher post-failure
 bisection throughput than the equal-cost fat-tree in the resiliency
 scenario."""
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.experiments import (Axis, Experiment, get_experiment, product,
                                run_experiment)
@@ -17,7 +17,7 @@ TOL = 1e-5
 
 def _row_parity(exp):
     rs_np = run_experiment(exp, backend="numpy", processes=2)
-    with enable_x64():
+    with jax.enable_x64(True):
         rs_jx = run_experiment(exp, backend="jax")   # megabatch default
     rows_np, rows_jx = rs_np.to_metrics(), rs_jx.to_metrics()
     assert len(rows_np) == len(rows_jx) == len(exp.points())

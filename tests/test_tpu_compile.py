@@ -1,0 +1,185 @@
+"""The engine's Pallas kernels and its megabatch program compile for a
+TPU v5e that is described, not attached.
+
+Interpret mode (`test_kernels_interpret.py`) cannot see what Mosaic
+refuses: i1 reductions, blocks off the (8, 128) tiling, programs that
+do not fit the chip.  Here each of the six engine kernels is lowered
+through Mosaic at the shapes of `flap_during_incast` (the acceptance
+grid) and `giga_fabric_storage` (4,096 hosts, sparse aggregation), and
+one whole megabatch program is compiled with the kernels on.
+
+The topology is described inside a module fixture, never at import:
+only one process at a time may load the TPU compiler's library, so
+under pytest-xdist only the worker given this file loads it.
+"""
+import functools
+import os
+import warnings
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels import jsq_route, link_load, plb_select, queue_ecn
+
+# the v5e's HBM
+DEVICE_BYTES = 16 * 10**9
+SCENARIOS = ("flap_during_incast", "giga_fabric_storage")
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    # a program compiled for a described chip is written to the
+    # persistent cache but cannot be read back without one
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        yield topologies.get_topology_desc(platform="tpu",
+                                           topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@functools.lru_cache(maxsize=None)
+def _dims(name):
+    """Engine operand sizes of a registry scenario: planes, leaves,
+    spines, hosts, and the megabatch flow bucket."""
+    from repro.netsim.jx.megabatch import FLOW_BUCKET_MIN, _bucket
+    from repro.scenarios import compile_scenario, get_scenario
+
+    spec = get_scenario(name)
+    t = spec.topo
+    F = _bucket(len(compile_scenario(spec).flows), FLOW_BUCKET_MIN)
+    return dict(P=t.n_planes, L=t.n_leaves, S=t.n_spines, H=t.n_hosts,
+                F=F)
+
+
+def _compile(fn, sharding, *shapes):
+    """Lower `fn` for the described chip and return the number of Mosaic
+    kernels (`tpu_custom_call`) in the compiled program."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=sharding)
+            for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    return compiled.as_text().count("tpu_custom_call")
+
+
+f32, b1 = jnp.float32, jnp.bool_
+_NIC_KW = dict(base_rtt_us=4.0, slot_us=10.0, ecn_thresh=4.0,
+               target_rtt_us=12.0, min_rate=0.01, md=0.7, ai=0.08,
+               rtt_gain=0.15, dcqcn_ai=0.01, alpha_g=0.0625)
+
+
+def _plane_split(d):
+    def fn(rate, elig, demand):
+        return [plb_select.plane_split(rate, elig, demand, mode=m,
+                                       min_rate=0.01, use_pallas=True,
+                                       interpret=False)
+                for m in ("spx", "dcqcn", "agg", "swlb")]
+    fp = (d["F"], d["P"])
+    return fn, [(fp, f32), (fp, b1), ((d["F"],), f32)], 4
+
+
+def _nic_update(d):
+    def fn(qmean, rate, alpha, esr):
+        return [queue_ecn.nic_update(qmean, rate, alpha, esr, mode=m,
+                                     use_pallas=True, interpret=False,
+                                     **_NIC_KW)
+                for m in ("spx", "dcqcn", "agg")]
+    fp = (d["F"], d["P"])
+    return fn, [(fp, f32)] * 3 + [((d["F"], 1), b1)], 3
+
+
+def _pair_fractions(d):
+    def fn(q, cap, w):
+        return jsq_route.pair_fractions(q, cap, w, use_pallas=True,
+                                        interpret=False)
+    s = (d["P"], d["L"], d["L"], d["S"])
+    return fn, [(s, f32)] * 3, 1
+
+
+def _bucket_load_bottleneck(d):
+    def fn(g, cap):
+        return link_load.bucket_load_bottleneck(
+            g, cap, ordered=False, use_pallas=True, interpret=False)
+    rows = 2 * d["L"] * d["S"]
+    return fn, [((d["P"], rows, 16), f32), ((d["P"], rows), f32)], 1
+
+
+def _bottleneck(d):
+    def fn(cap, load, acc, acc_load):
+        return (link_load.bottleneck(cap, load, use_pallas=True,
+                                     interpret=False),
+                link_load.bottleneck(acc, acc_load, use_pallas=True,
+                                     interpret=False))
+    links, hosts = (d["P"], d["L"], d["S"]), (d["H"], d["P"])
+    return fn, [(links, f32)] * 2 + [(hosts, f32)] * 2, 2
+
+
+def _queue_update(d):
+    def fn(q, load, cap):
+        return queue_ecn.queue_update(q, load, cap, q_cap=64.0,
+                                      use_pallas=True, interpret=False)
+    return fn, [((d["P"], d["L"], d["S"]), f32)] * 3, 1
+
+
+KERNELS = {"plane_split": _plane_split, "nic_update": _nic_update,
+           "pair_fractions": _pair_fractions,
+           "bucket_load_bottleneck": _bucket_load_bottleneck,
+           "bottleneck": _bottleneck, "queue_update": _queue_update}
+
+
+@pytest.mark.parametrize("scenario", SCENARIOS)
+@pytest.mark.parametrize("kernel", sorted(KERNELS))
+def test_engine_kernel_lowers_through_mosaic(one_chip, kernel, scenario):
+    fn, shapes, n_calls = KERNELS[kernel](_dims(scenario))
+    assert _compile(fn, one_chip, *shapes) >= n_calls
+
+
+def test_megabatch_program_compiles_with_kernels(one_chip, monkeypatch):
+    """A routing x NIC grid of `flap_during_incast` as one megabatch
+    program, the Pallas kernels compiled (not interpreted) inside its
+    scan, and the program fits the chip."""
+    from repro.kernels import backend
+    from repro.netsim.jx import engine
+    from repro.netsim.jx.megabatch import megabatch_programs
+    from repro.scenarios import compile_scenario, get_scenario
+
+    monkeypatch.setenv("REPRO_NETSIM_PALLAS", "1")
+    monkeypatch.setattr(backend, "pallas_interpret",
+                        lambda override=None: False)
+    # a private jit cache: the program traced here must not serve a
+    # later CPU run of the same config
+    monkeypatch.setattr(engine, "_JIT_CACHE", {})
+    base = get_scenario("flap_during_incast")
+    points = [compile_scenario(base.with_sim(routing=r, nic=n,
+                                             backend="jax"))
+              for r in ("ar", "war", "ecmp") for n in ("spx", "dcqcn")]
+    (fn, args), = megabatch_programs(points, n_devices=1)
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            np.shape(a), jax.dtypes.canonicalize_dtype(np.asarray(a).dtype),
+            sharding=one_chip), args)
+    with warnings.catch_warnings():
+        # the scan rewrites the donated carry; not every leaf aliases
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
+        compiled = fn.lower(*avals).compile()
+    assert compiled.as_text().count("tpu_custom_call") > 0
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < DEVICE_BYTES

@@ -7,9 +7,9 @@ local to this file regardless of suite order.
 """
 import json
 
+import jax
 import numpy as np
 import pytest
-from jax.experimental import enable_x64
 
 from repro.core.telemetry import bw_histogram, classify_histogram, \
     find_stragglers
@@ -57,7 +57,7 @@ def test_trace_numpy_jax_parity_fig12():
     the jx scan on the fig12 flap scenario."""
     spec = _fig12(slots=137)
     rn = compile_scenario(spec).run()
-    with enable_x64():
+    with jax.enable_x64(True):
         rj = run_compiled(compile_scenario(spec))
     assert set(rn.trace) == set(TRACE_FIELDS) | {"slot"}
     _assert_traces_close(rn.trace, rj.trace, "fig12")
@@ -68,7 +68,7 @@ def test_trace_decimation_and_field_subset():
     subset captures only those fields."""
     spec = _fig12(slots=137, every=7, fields=("host_bw", "queue"))
     rn = compile_scenario(spec).run()
-    with enable_x64():
+    with jax.enable_x64(True):
         rj = run_compiled(compile_scenario(spec))
     expect = np.arange(0, 137, 7)
     assert np.array_equal(rn.trace["slot"], expect)
@@ -117,7 +117,7 @@ def test_megabatch_traced_one_compile_per_bucket_and_trace_parity():
     points = [get_scenario(s).with_sim(slots=73, routing=r, trace=ts)
               for s in ("flap_during_incast", "staggered_incast_bursts")
               for r in ("ar", "ecmp")]
-    with enable_x64():
+    with jax.enable_x64(True):
         compiled = [compile_scenario(p) for p in points]
         reset_dispatch_stats()
         res = {}
