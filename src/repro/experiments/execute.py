@@ -36,6 +36,7 @@ from dataclasses import replace
 from functools import partial
 from typing import Callable, Dict, List, Optional
 
+from repro.netsim.flight import collect_dispatch, span
 from repro.scenarios.compile import compile_scenario
 from repro.scenarios.runner import ScenarioMetrics, distill_metrics, run_point
 from repro.scenarios.spec import ScenarioSpec
@@ -119,22 +120,49 @@ def execute_points(points: List[ScenarioSpec],
     `compile_cache_dir` places the persistent compilation cache unless
     `JAX_COMPILATION_CACHE_DIR` is set (see `enable_compile_cache`).
 
-    `flight`, when a dict, is filled with the executor flight-recorder
-    summary: backend/mode, total wall clock, per-point wall times (JAX
-    points share one launch, so their cost is the finalized group's wall
-    amortized over its points), and — on the JAX paths — this sweep's
-    own dispatch/compile counts (`collect_dispatch`) plus any float32
-    bytes_total overflow conditions hit while preparing it."""
-    emit = on_result or (lambda i, m: None)
-    t_start = time.perf_counter()
-    point_walls: List[Dict] = []
+    The call is one sweep of the flight recorder
+    (`repro.netsim.flight`): its host spans (`repro.execute` around the
+    whole call, and inside it `repro.scenario`, `repro.prep.*`,
+    `repro.plan`, `repro.dispatch`, `repro.launch`, `repro.finalize.*`
+    and `repro.distill`) carry the sweep's id, and sit on the profiler's
+    clock whenever a JAX profiler runs.
 
-    def _done(mode: str, **kw) -> None:
-        if flight is not None:
-            flight.update(
-                {"backend": backend, "mode": mode, "n_points": len(points),
-                 "wall_s": round(time.perf_counter() - t_start, 6),
-                 "points": point_walls, **kw})
+    `flight`, when a dict, is filled with the sweep's summary:
+    backend/mode, total wall clock, the sweep's id (`sweep`, the `sweep`
+    metadata of its spans), `phases` (host seconds per span name, the
+    prep worker thread's included) and `counters` (among them
+    `flow_slots_real` and `flow_slots_launched`, `launch_bytes`,
+    `xla_compiles`/`xla_compile_s` and `cache_loads`/`cache_load_s`).  The NumPy paths add per-point wall
+    times (`points`), each taken where the point ran; the JAX paths
+    add this sweep's dispatch/compile counts (`dispatch_stats`) plus
+    any float32 bytes_total overflow conditions hit while preparing
+    it."""
+    t_start = time.perf_counter()
+    with collect_dispatch() as rec:
+        with span("repro.execute"):
+            backend, out, summary = _execute(
+                points, processes, backend, derive,
+                on_result or (lambda i, m: None), jx_dispatch,
+                compile_cache_dir)
+    if flight is not None:
+        if backend == "jax":
+            summary["dispatch_stats"] = rec.snapshot()
+        phases, counters = rec.record()
+        flight.update(
+            {"backend": backend, "mode": summary.pop("mode"),
+             "n_points": len(points),
+             "wall_s": round(time.perf_counter() - t_start, 6),
+             **summary, "sweep": rec.sweep, "phases": phases,
+             "counters": counters})
+    return out
+
+
+def _execute(points: List[ScenarioSpec], processes: Optional[int],
+             backend: Optional[str], derive: Optional[Callable],
+             emit: OnResult, jx_dispatch: Optional[str],
+             compile_cache_dir: Optional[str]):
+    """`execute_points` without its flight record: returns the resolved
+    backend, the metrics in point order and the path's summary."""
     if backend is None:
         inherited = {p.sim.backend for p in points}
         if len(inherited) > 1:
@@ -150,11 +178,9 @@ def execute_points(points: List[ScenarioSpec],
             raise ValueError(
                 f"unknown jx_dispatch {mode!r}; expected one of "
                 f"{JX_DISPATCH_MODES}")
-        out, stats, overflows, pipeline = _execute_jax(
-            points, derive, emit, mode, point_walls)
-        _done(mode, dispatch_stats=stats, f32_overflows=overflows,
-              pipeline=pipeline)
-        return out
+        out, overflows, pipeline = _execute_jax(points, derive, emit, mode)
+        return backend, out, {"mode": mode, "f32_overflows": overflows,
+                              "pipeline": pipeline}
     if backend != "numpy":
         raise ValueError(
             f"unknown backend {backend!r}; expected 'numpy' or 'jax'")
@@ -166,20 +192,19 @@ def execute_points(points: List[ScenarioSpec],
     if processes is None:
         processes = min(len(points), os.cpu_count() or 1)
     runner = partial(_timed_point, derive=derive)
+    point_walls: List[Dict] = []
 
-    def _serial(results=None):
+    def _serial():
         results = []
         for i, p in enumerate(points):
             m, w = runner(p)
             point_walls.append({"index": i, "wall_s": round(w, 6)})
             emit(i, m)
             results.append(m)
-        return results
+        return backend, results, {"mode": "serial", "points": point_walls}
 
     if processes <= 1 or len(points) <= 1:
-        results = _serial()
-        _done("serial")
-        return results
+        return _serial()
     # forking a parent whose XLA backend is live (multithreaded) can
     # deadlock the workers, so after a backend="jax" sweep ran in this
     # process switch to the spawn family.  Merely having jax *imported*
@@ -191,9 +216,7 @@ def execute_points(points: List[ScenarioSpec],
     if _xla_backend_live():
         main_file = getattr(sys.modules.get("__main__"), "__file__", None)
         if main_file is not None and not os.path.exists(main_file):
-            results = _serial()
-            _done("serial")
-            return results
+            return _serial()
         methods = multiprocessing.get_all_start_methods()
         ctx = multiprocessing.get_context(
             "forkserver" if "forkserver" in methods else "spawn")
@@ -212,8 +235,8 @@ def execute_points(points: List[ScenarioSpec],
                 point_walls.append({"index": i, "wall_s": round(w, 6)})
                 out[i] = m
                 emit(i, m)
-    _done("pool", processes=processes)
-    return out
+    return backend, out, {"mode": "pool", "points": point_walls,
+                          "processes": processes}
 
 
 def _xla_backend_live() -> bool:
@@ -233,8 +256,7 @@ def _xla_backend_live() -> bool:
 
 
 def _execute_jax(points: List[ScenarioSpec], derive: Optional[Callable],
-                 emit: OnResult, mode: str = "megabatch",
-                 point_walls: Optional[List[Dict]] = None):
+                 emit: OnResult, mode: str = "megabatch"):
     """Batched single-process sweep.
 
     'megabatch' (default): every structurally compatible point — any
@@ -256,98 +278,87 @@ def _execute_jax(points: List[ScenarioSpec], derive: Optional[Callable],
     CPU execution is async), with
     `XLA_FLAGS=--xla_force_host_platform_device_count=N` sharding batch
     axes over the N host devices, and completed rows stream out per
-    finalized batch."""
-    from repro.netsim.jx.engine import collect_dispatch, f32_overflow_log
+    finalized batch.  Launches, spans and counters go to the caller's
+    `collect_dispatch` scope, which the prep worker adopts."""
+    from repro.netsim.jx.engine import f32_overflow_log
 
     results: List[Optional[ScenarioMetrics]] = [None] * len(points)
     n_overflows0 = len(f32_overflow_log())
 
     def deliver(i, c, r):
-        m = distill_metrics(points[i], c, r)
+        with span("repro.distill"):
+            m = distill_metrics(points[i], c, r)
         if derive is not None:
             m.extra.update(derive(points[i], c, r))
         results[i] = m
         emit(i, m)
 
-    def record_group(idxs: List[int], wall_s: float) -> None:
-        # one fused launch per group: its wall clock amortizes evenly
-        if point_walls is not None:
-            each = round(wall_s / max(len(idxs), 1), 6)
-            point_walls.extend({"index": i, "wall_s": each}
-                               for i in idxs)
+    def compile_point(p):
+        with span("repro.scenario"):
+            return compile_scenario(p)
 
-    # collect_dispatch attributes launches to THIS sweep: the
-    # before/after global-counter delta it replaces misattributed any
-    # launches concurrent executors made on other threads
     pipeline: Dict = {}
-    with collect_dispatch() as counter:
-        if mode == "megabatch":
-            from repro.netsim.jx.engine import (adopt_dispatch,
-                                                current_collectors)
-            from repro.netsim.jx.megabatch import (dispatch_planned,
-                                                   finalize_group,
-                                                   plan_megabatch)
+    if mode == "megabatch":
+        from repro.netsim.flight import adopt_dispatch, current_collectors
+        from repro.netsim.jx.megabatch import (dispatch_planned,
+                                               finalize_group,
+                                               plan_megabatch)
 
-            import jax
+        import jax
 
-            compiled = [compile_scenario(p) for p in points]
-            caches, planned = plan_megabatch(compiled)
-            collectors = current_collectors()
-            x64 = bool(jax.config.jax_enable_x64)
+        compiled = [compile_point(p) for p in points]
+        caches, planned = plan_megabatch(compiled)
+        collectors = current_collectors()
+        x64 = bool(jax.config.jax_enable_x64)
 
-            def prep(group):
-                # the worker thread runs outside the main thread's
-                # collect_dispatch scope AND its thread-local jax
-                # config overrides (`jax.enable_x64(...)` contexts): adopt
-                # the counters and re-assert the caller's x64 state so the
-                # launch traces with the caller's dtypes
-                with adopt_dispatch(collectors), jax.enable_x64(x64):
-                    return dispatch_planned(group, caches)
+        def prep(group):
+            # the worker thread runs outside the main thread's
+            # collect_dispatch scope AND its thread-local jax
+            # config overrides (`jax.enable_x64(...)` contexts): adopt
+            # the counters and re-assert the caller's x64 state so the
+            # launch traces with the caller's dtypes
+            with adopt_dispatch(collectors), jax.enable_x64(x64):
+                return dispatch_planned(group, caches)
 
-            launches = 0
-            # single prep worker: host prep (memoized flow arrays,
-            # fault timelines, ECMP replays) of bucket k+1 overlaps
-            # device execution of bucket k (JAX dispatch is async);
-            # the main thread finalizes rows as buckets retire
-            with ThreadPoolExecutor(max_workers=1) as pool:
-                futs = [pool.submit(prep, g) for g in planned]
-                for fut in futs:
-                    for idxs, handle in fut.result():
-                        launches += 1
-                        tg = time.perf_counter()
-                        for i, r in zip(idxs, finalize_group(handle)):
-                            deliver(i, compiled[i], r)
-                        record_group(idxs, time.perf_counter() - tg)
-            # >1 launch means prep/execute/finalize actually overlapped
-            # (launch k+1's host prep runs while the device executes k)
-            pipeline = {"groups": len(planned), "launches": launches,
-                        "pipelined": launches > 1}
-        else:
-            from repro.netsim.jx.engine import (dispatch_compiled_batch,
-                                                finalize_batch)
+        launches = 0
+        # single prep worker: host prep (memoized flow arrays,
+        # fault timelines, ECMP replays) of bucket k+1 overlaps
+        # device execution of bucket k (JAX dispatch is async);
+        # the main thread finalizes rows as buckets retire
+        with ThreadPoolExecutor(max_workers=1) as pool:
+            futs = [pool.submit(prep, g) for g in planned]
+            for fut in futs:
+                for idxs, handle in fut.result():
+                    launches += 1
+                    for i, r in zip(idxs, finalize_group(handle)):
+                        deliver(i, compiled[i], r)
+        # >1 launch means prep/execute/finalize actually overlapped
+        # (launch k+1's host prep runs while the device executes k)
+        pipeline = {"groups": len(planned), "launches": launches,
+                    "pipelined": launches > 1}
+    else:
+        from repro.netsim.jx.engine import (dispatch_compiled_batch,
+                                            finalize_batch)
 
-            order: List = []
-            groups: Dict = {}
-            for i, p in enumerate(points):
-                key = replace(p,
-                              sim=replace(p.sim, seed=0,
-                                          backend="numpy"),
-                              workload_seed=0)
-                if key not in groups:
-                    groups[key] = []
-                    order.append(key)
-                groups[key].append(i)
-            dispatched = []
-            for key in order:
-                idxs = groups[key]
-                compiled = [compile_scenario(points[i]) for i in idxs]
-                dispatched.append((idxs, compiled,
-                                   dispatch_compiled_batch(compiled)))
-            for idxs, compiled, handle in dispatched:
-                tg = time.perf_counter()
-                for i, c, r in zip(idxs, compiled,
-                                   finalize_batch(handle)):
-                    deliver(i, c, r)
-                record_group(idxs, time.perf_counter() - tg)
+        order: List = []
+        groups: Dict = {}
+        for i, p in enumerate(points):
+            key = replace(p,
+                          sim=replace(p.sim, seed=0,
+                                      backend="numpy"),
+                          workload_seed=0)
+            if key not in groups:
+                groups[key] = []
+                order.append(key)
+            groups[key].append(i)
+        dispatched = []
+        for key in order:
+            idxs = groups[key]
+            compiled = [compile_point(points[i]) for i in idxs]
+            dispatched.append((idxs, compiled,
+                               dispatch_compiled_batch(compiled)))
+        for idxs, compiled, handle in dispatched:
+            for i, c, r in zip(idxs, compiled, finalize_batch(handle)):
+                deliver(i, c, r)
     overflows = list(f32_overflow_log()[n_overflows0:])
-    return results, counter.snapshot(), overflows, pipeline
+    return results, overflows, pipeline

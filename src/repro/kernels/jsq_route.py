@@ -104,6 +104,7 @@ def pair_fractions(q: jax.Array, cap: jax.Array, w: jax.Array, *,
         out_specs=pl.BlockSpec((br, S), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((q2.shape[0], S), jnp.float32),
         interpret=backend.pallas_interpret(interpret),
+        name="pair_fractions",
     )(q2.astype(jnp.float32), cap2.astype(jnp.float32),
       w2.astype(jnp.float32))
     return out[:R].reshape(*lead, S).astype(q.dtype)
@@ -139,6 +140,7 @@ def jsq_route(queues: jax.Array, up_mask: jax.Array, weights: jax.Array,
         out_specs=pl.BlockSpec((bp, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((pkt_hash.shape[0], 1), jnp.int32),
         interpret=backend.pallas_interpret(interpret),
+        name="jsq_route",
     )(queues[None, :].astype(jnp.float32),
       up_mask[None, :].astype(jnp.float32),
       weights[None, :].astype(jnp.float32),

@@ -93,6 +93,7 @@ def bucket_load_bottleneck(g: jax.Array, cap: jax.Array, *,
             jax.ShapeDtypeStruct((g2.shape[0], 1), jnp.float32),
         ],
         interpret=backend.pallas_interpret(interpret),
+        name="bucket_load_bottleneck",
     )(g2.astype(jnp.float32), cap2.astype(jnp.float32))
     return (load[:rows, 0].reshape(P, R).astype(g.dtype),
             frac[:rows, 0].reshape(P, R).astype(g.dtype))
@@ -124,6 +125,7 @@ def bottleneck(cap: jax.Array, load: jax.Array, *, eps: float = EPS,
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(slabs[0].shape, jnp.float32),
         interpret=backend.pallas_interpret(interpret),
+        name="bottleneck",
     )(*slabs)
     return backend.from_lanes(out, cap.shape, cap.dtype)
 

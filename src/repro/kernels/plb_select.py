@@ -116,6 +116,7 @@ def plane_split(rate: jax.Array, eligible: jax.Array, demand: jax.Array,
         out_specs=pl.BlockSpec((bp, P), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rate.shape[0], P), jnp.float32),
         interpret=backend.pallas_interpret(interpret),
+        name="plane_split",
     )(rate.astype(jnp.float32), eligible.astype(jnp.float32),
       demand[:, None].astype(jnp.float32))
     return out[:F].astype(rate.dtype)
@@ -152,6 +153,7 @@ def plb_select(rate_allow: jax.Array, eligible: jax.Array,
         out_specs=pl.BlockSpec((bp, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((pkt_hash.shape[0], 1), jnp.int32),
         interpret=backend.pallas_interpret(interpret),
+        name="plb_select",
     )(rate_allow[None, :].astype(jnp.float32),
       eligible[None, :].astype(jnp.float32),
       local_queue[None, :].astype(jnp.float32),

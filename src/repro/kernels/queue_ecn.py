@@ -61,6 +61,7 @@ def queue_update(q: jax.Array, load: jax.Array, cap: jax.Array, *,
         out_specs=[spec] * 2,
         out_shape=[jax.ShapeDtypeStruct(slabs[0].shape, jnp.float32)] * 2,
         interpret=backend.pallas_interpret(interpret),
+        name="queue_update",
     )(*slabs)
     return (backend.from_lanes(qn, q.shape, q.dtype),
             backend.from_lanes(util, q.shape, q.dtype))
@@ -162,6 +163,7 @@ def nic_update(qmean: jax.Array, rate: jax.Array, alpha: jax.Array,
         out_shape=[jax.ShapeDtypeStruct((q2.shape[0], P),
                                         jnp.float32)] * 4,
         interpret=backend.pallas_interpret(interpret),
+        name="nic_update",
     )(q2.astype(jnp.float32), r2.astype(jnp.float32),
       a2.astype(jnp.float32), e2.astype(jnp.float32))
     return (rtt[:F].astype(qmean.dtype), ecn[:F].astype(qmean.dtype),

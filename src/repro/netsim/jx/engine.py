@@ -58,7 +58,6 @@ from __future__ import annotations
 
 import os
 import threading
-from contextlib import contextmanager
 from dataclasses import dataclass, replace
 from functools import partial
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
@@ -80,6 +79,10 @@ from repro.netsim.cc import (DCQCN_AI, DCQCN_ALPHA_G, MIN_RATE,
                              TARGET_RTT_US)
 from repro.netsim.fabric import (AR_TEMPERATURE, ECN_QUEUE_THRESH,
                                  JSQ_BINS, Q_CAP, FlowArrays)
+# the counters' public names stay importable from the engine
+from repro.netsim.flight import (collect_dispatch,  # noqa: F401
+                                 dispatch_stats, record_launch,
+                                 reset_dispatch_stats, watch_compiles)
 from repro.netsim.sim import SimConfig
 from repro.trace import TraceSpec
 
@@ -322,77 +325,13 @@ def stack_idx_for(routing: str, nic: str) -> Tuple[int, bool, int, bool]:
 
 
 # ---------------------------------------------------------------------------
-# dispatch bookkeeping: launches + (program-level) compiles
+# dispatch bookkeeping: launches + (program-level) compiles, host spans
+# and counters live in `repro.netsim.flight`; the engine fingerprints its
+# programs and counts JAX's compiles from its first import on
 # ---------------------------------------------------------------------------
 
-_STATS_LOCK = threading.RLock()
-_STATS = {"dispatches": 0, "compiles": 0}
-_SEEN_PROGRAMS: set = set()
 _JIT_CACHE: Dict[Tuple, Callable] = {}
-_COLLECTORS = threading.local()
-
-
-class DispatchCounter:
-    """Per-scope launch/compile counters (see `collect_dispatch`).
-    Incremented only under `_STATS_LOCK`; `snapshot()` returns a plain
-    dict in the `dispatch_stats` shape."""
-
-    __slots__ = ("dispatches", "compiles")
-
-    def __init__(self) -> None:
-        self.dispatches = 0
-        self.compiles = 0
-
-    def snapshot(self) -> Dict[str, int]:
-        with _STATS_LOCK:
-            return {"dispatches": self.dispatches,
-                    "compiles": self.compiles}
-
-
-@contextmanager
-def collect_dispatch():
-    """Attribute launches made by *this thread* inside the block to a
-    fresh `DispatchCounter`.  Unlike sampling the module-global
-    `dispatch_stats` before/after (which misattributes launches from
-    concurrent executors), a collector only sees its own thread's
-    dispatches.  Collectors nest: every active one on the thread counts
-    each launch."""
-    stack = getattr(_COLLECTORS, "stack", None)
-    if stack is None:
-        stack = _COLLECTORS.stack = []
-    counter = DispatchCounter()
-    stack.append(counter)
-    try:
-        yield counter
-    finally:
-        stack.remove(counter)
-
-
-def current_collectors() -> Tuple["DispatchCounter", ...]:
-    """Snapshot of the collectors active on *this* thread — capture it
-    before handing work to a helper thread, then `adopt_dispatch` the
-    snapshot there so `collect_dispatch` scopes survive the hop."""
-    return tuple(getattr(_COLLECTORS, "stack", None) or ())
-
-
-@contextmanager
-def adopt_dispatch(collectors: Tuple["DispatchCounter", ...]):
-    """Attribute this thread's launches to collectors captured on
-    another thread (via `current_collectors`).  The pipelined megabatch
-    executor dispatches from a worker thread while the caller's
-    `collect_dispatch` scope lives on the main thread — without
-    adoption those launches would vanish from the sweep's own counter.
-    Collectors already active on this thread are not double-counted."""
-    stack = getattr(_COLLECTORS, "stack", None)
-    if stack is None:
-        stack = _COLLECTORS.stack = []
-    adopted = [c for c in collectors if c not in stack]
-    stack.extend(adopted)
-    try:
-        yield
-    finally:
-        for c in adopted:
-            stack.remove(c)
+watch_compiles()
 
 
 def _device_fingerprint() -> Tuple:
@@ -406,37 +345,8 @@ def _record_launch(tag: str, key, args) -> None:
     shapes = tuple(
         (np.shape(leaf), str(getattr(leaf, "dtype", type(leaf))))
         for leaf in jax.tree_util.tree_leaves(args))
-    fp = (tag, key, shapes, bool(jax.config.jax_enable_x64),
-          _device_fingerprint())
-    with _STATS_LOCK:
-        _STATS["dispatches"] += 1
-        fresh = fp not in _SEEN_PROGRAMS
-        if fresh:
-            _SEEN_PROGRAMS.add(fp)
-            _STATS["compiles"] += 1
-        for counter in getattr(_COLLECTORS, "stack", ()):
-            counter.dispatches += 1
-            if fresh:
-                counter.compiles += 1
-
-
-def dispatch_stats() -> Dict[str, int]:
-    """Process-wide counters since the last reset: `dispatches` =
-    device-program launches, `compiles` = launches whose (program,
-    shapes, devices) fingerprint had not been seen before in this
-    process.  For attributing launches to one executor, prefer
-    `collect_dispatch` — these globals count every thread."""
-    with _STATS_LOCK:
-        return dict(_STATS)
-
-
-def reset_dispatch_stats() -> None:
-    """Zero the counters.  The seen-program set is *not* cleared — it
-    mirrors the lifetime of jax's own executable caches, so a warm
-    re-run correctly reports 0 compiles."""
-    with _STATS_LOCK:
-        _STATS["dispatches"] = 0
-        _STATS["compiles"] = 0
+    record_launch((tag, key, shapes, bool(jax.config.jax_enable_x64),
+                   _device_fingerprint()))
 
 
 # ---------------------------------------------------------------------------
@@ -970,32 +880,34 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
                load_fn: Callable, carry: SimCarry, xs):
     # timelines are piecewise-constant, so the scan carries only the
     # (n_seg, ...) boundary snapshots and gathers the current segment
-    t, seg = xs
-    up = seg_up[seg] * cfg.uplink_cap                     # (P, L, S|A)
-    down = seg_down[seg] * cfg.uplink_cap                 # (P, S|A, L)
-    acc = (seg_acc[seg] * cfg.access_cap).T               # (H, P)
-    up2 = seg_up2[seg] * cfg.core_cap                     # (P, pods, C)
-    down2 = seg_down2[seg] * cfg.core_cap
-    if cfg.react:
-        # routing-visible (detection-lagged) fabric view; access never
-        # lags (NIC probes see host faults directly)
-        upv = seg_vup[seg] * cfg.uplink_cap
-        downv = seg_vdown[seg] * cfg.uplink_cap
-        up2v = seg_vup2[seg] * cfg.core_cap
-        down2v = seg_vdown2[seg] * cfg.core_cap
-    else:
-        # dead operands: routing sees physical truth, the traced
-        # program is identical to the pre-reaction engine
-        upv, downv, up2v, down2v = up, down, up2, down2
+    with jax.named_scope("slot/segment"):
+        t, seg = xs
+        up = seg_up[seg] * cfg.uplink_cap                     # (P, L, S|A)
+        down = seg_down[seg] * cfg.uplink_cap                 # (P, S|A, L)
+        acc = (seg_acc[seg] * cfg.access_cap).T               # (H, P)
+        up2 = seg_up2[seg] * cfg.core_cap                     # (P, pods, C)
+        down2 = seg_down2[seg] * cfg.core_cap
+        if cfg.react:
+            # routing-visible (detection-lagged) fabric view; access never
+            # lags (NIC probes see host faults directly)
+            upv = seg_vup[seg] * cfg.uplink_cap
+            downv = seg_vdown[seg] * cfg.uplink_cap
+            up2v = seg_vup2[seg] * cfg.core_cap
+            down2v = seg_vdown2[seg] * cfg.core_cap
+        else:
+            # dead operands: routing sees physical truth, the traced
+            # program is identical to the pre-reaction engine
+            upv, downv, up2v, down2v = up, down, up2, down2
 
-    demand = jnp.where(carry.done | (t < fb.start_slot), 0.0, fb.demand)
-    if cfg.n_phases:
-        # schedule workloads: piecewise-constant per-phase demand
-        # multipliers, gathered per segment exactly like the capacity
-        # snapshots above (lane 0 is the always-1.0 lane)
-        demand = demand * seg_dem[seg][fb.phase]
-    offered = _plane_split(cfg, carry.nic, demand, stack)  # (F, P)
-    fabric_rate = jnp.where(fb.same_leaf[:, None], 0.0, offered)
+    with jax.named_scope("slot/plane_split"):
+        demand = jnp.where(carry.done | (t < fb.start_slot), 0.0, fb.demand)
+        if cfg.n_phases:
+            # schedule workloads: piecewise-constant per-phase demand
+            # multipliers, gathered per segment exactly like the capacity
+            # snapshots above (lane 0 is the always-1.0 lane)
+            demand = demand * seg_dem[seg][fb.phase]
+        offered = _plane_split(cfg, carry.nic, demand, stack)  # (F, P)
+        fabric_rate = jnp.where(fb.same_leaf[:, None], 0.0, offered)
 
     # ---- link loads + per-flow fabric throughput/queue, without any
     # (F, P, J) load intermediate: AR/WAR fractions are leaf-pair
@@ -1006,103 +918,110 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
     # also return stage-B loads); under traced dispatch `lax.switch`
     # evaluates both branches for the whole batch and selects per
     # element.
-    use_war = cfg.routing == "war" if stack is None else stack.is_war
-    if cfg.kind == "fat_tree":
-        branches = [
-            partial(_route_pair_ft, cfg, carry, fabric_rate, up, down,
-                    up2, down2, upv, downv, up2v, down2v, aggs,
-                    pair_idx, use_war),
-            partial(_route_ecmp_ft, cfg, carry, fabric_rate, up, down,
-                    up2, down2, fb, assign_segments, load_fn, seg)]
-    else:
-        branches = [
-            partial(_route_pair, cfg, carry, fabric_rate, up, down,
-                    upv, downv, aggs, pair_idx, use_war),
-            partial(_route_ecmp, cfg, carry, fabric_rate, up, down,
-                    fb, assign_segments, load_fn, seg)]
-    if stack is None:
-        routed = branches[1 if cfg.routing == "ecmp" else 0]()
-    elif isinstance(stack.route, int):
-        # lane-sorted megabatch: the dispatcher grouped elements by
-        # route, so the per-element index is concrete within the
-        # lane and only that branch is traced (no switch tax)
-        routed = branches[stack.route]()
-    else:
-        routed = jax.lax.switch(stack.route, branches)
-    bh = routed[-1] if cfg.react else None
-    routed = routed[:-1] if cfg.react else routed
-    if cfg.kind == "fat_tree":
-        load_up, load_down, loadB_up, loadB_dn, through, qmean = routed
-    else:
-        load_up, load_down, through, qmean = routed
+    with jax.named_scope("slot/route"):
+        use_war = cfg.routing == "war" if stack is None else stack.is_war
+        if cfg.kind == "fat_tree":
+            branches = [
+                partial(_route_pair_ft, cfg, carry, fabric_rate, up, down,
+                        up2, down2, upv, downv, up2v, down2v, aggs,
+                        pair_idx, use_war),
+                partial(_route_ecmp_ft, cfg, carry, fabric_rate, up, down,
+                        up2, down2, fb, assign_segments, load_fn, seg)]
+        else:
+            branches = [
+                partial(_route_pair, cfg, carry, fabric_rate, up, down,
+                        upv, downv, aggs, pair_idx, use_war),
+                partial(_route_ecmp, cfg, carry, fabric_rate, up, down,
+                        fb, assign_segments, load_fn, seg)]
+        if stack is None:
+            routed = branches[1 if cfg.routing == "ecmp" else 0]()
+        elif isinstance(stack.route, int):
+            # lane-sorted megabatch: the dispatcher grouped elements by
+            # route, so the per-element index is concrete within the
+            # lane and only that branch is traced (no switch tax)
+            routed = branches[stack.route]()
+        else:
+            routed = jax.lax.switch(stack.route, branches)
+        bh = routed[-1] if cfg.react else None
+        routed = routed[:-1] if cfg.react else routed
+        if cfg.kind == "fat_tree":
+            load_up, load_down, loadB_up, loadB_dn, through, qmean = routed
+        else:
+            load_up, load_down, through, qmean = routed
 
-    load_acc_tx = _host_sum(cfg, offered, fb.src, aggs.src)  # (H, P)
-    load_acc_rx = _host_sum(cfg, offered, fb.dst, aggs.dst)
+    with jax.named_scope("slot/host_load"):
+        load_acc_tx = _host_sum(cfg, offered, fb.src, aggs.src)  # (H, P)
+        load_acc_rx = _host_sum(cfg, offered, fb.dst, aggs.dst)
 
     # ---- bottleneck scaling (access; fabric scaling lives in the
     # routing branches) ----
-    f_acc_tx = _k_bottleneck(acc, load_acc_tx, eps=_EPS,
-                             use_pallas=cfg.use_pallas)
-    f_acc_rx = _k_bottleneck(acc, load_acc_rx, eps=_EPS,
-                             use_pallas=cfg.use_pallas)
-    up_alive_tx = acc[fb.src] > _EPS                      # (F, P)
-    up_alive_rx = acc[fb.dst] > _EPS
+    with jax.named_scope("slot/access_scale"):
+        f_acc_tx = _k_bottleneck(acc, load_acc_tx, eps=_EPS,
+                                 use_pallas=cfg.use_pallas)
+        f_acc_rx = _k_bottleneck(acc, load_acc_rx, eps=_EPS,
+                                 use_pallas=cfg.use_pallas)
+        up_alive_tx = acc[fb.src] > _EPS                      # (F, P)
+        up_alive_rx = acc[fb.dst] > _EPS
 
-    local = jnp.where(fb.same_leaf[:, None], offered, 0.0)
-    acc_scale = jnp.minimum(f_acc_tx[fb.src], f_acc_rx[fb.dst])
-    achieved_pp = (through + local) * acc_scale
-    achieved_pp = jnp.where(up_alive_tx & up_alive_rx, achieved_pp, 0.0)
-    qmean = jnp.where(fb.same_leaf[:, None], 0.0, qmean)
+        local = jnp.where(fb.same_leaf[:, None], offered, 0.0)
+        acc_scale = jnp.minimum(f_acc_tx[fb.src], f_acc_rx[fb.dst])
+        achieved_pp = (through + local) * acc_scale
+        achieved_pp = jnp.where(up_alive_tx & up_alive_rx, achieved_pp, 0.0)
+        qmean = jnp.where(fb.same_leaf[:, None], 0.0, qmean)
 
     # ---- queue evolution (stage B only exists on fat_tree; the kind
     # is static, so leaf_spine programs carry the placeholders through
     # untouched) ----
-    q_up, util = _k_queue_update(carry.q_up, load_up, up,
-                                 q_cap=cfg.q_cap, eps=_EPS,
-                                 use_pallas=cfg.use_pallas)
-    q_down, _ = _k_queue_update(carry.q_down, load_down, down,
-                                q_cap=cfg.q_cap, eps=_EPS,
-                                use_pallas=cfg.use_pallas)
-    if cfg.kind == "fat_tree":
-        q2_up, _ = _k_queue_update(carry.q2_up, loadB_up, up2,
-                                   q_cap=cfg.q_cap, eps=_EPS,
-                                   use_pallas=cfg.use_pallas)
-        q2_down, _ = _k_queue_update(carry.q2_down, loadB_dn, down2,
+    with jax.named_scope("slot/queue"):
+        q_up, util = _k_queue_update(carry.q_up, load_up, up,
                                      q_cap=cfg.q_cap, eps=_EPS,
                                      use_pallas=cfg.use_pallas)
-    else:
-        q2_up, q2_down = carry.q2_up, carry.q2_down
+        q_down, _ = _k_queue_update(carry.q_down, load_down, down,
+                                    q_cap=cfg.q_cap, eps=_EPS,
+                                    use_pallas=cfg.use_pallas)
+        if cfg.kind == "fat_tree":
+            q2_up, _ = _k_queue_update(carry.q2_up, loadB_up, up2,
+                                       q_cap=cfg.q_cap, eps=_EPS,
+                                       use_pallas=cfg.use_pallas)
+            q2_down, _ = _k_queue_update(carry.q2_down, loadB_dn, down2,
+                                         q_cap=cfg.q_cap, eps=_EPS,
+                                         use_pallas=cfg.use_pallas)
+        else:
+            q2_up, q2_down = carry.q2_up, carry.q2_down
 
     # ---- NIC control update (pre-stall rates, as in run_sim; rtt/ecn
     # derive from qmean inside the fused kernel) ----
-    probe_ok = (acc[fb.src] > _EPS) & (acc[fb.dst] > _EPS)
-    nic, rtt, ecn = _nic_update(cfg, carry.nic, qmean, probe_ok, t,
-                                stack)
+    with jax.named_scope("slot/nic"):
+        probe_ok = (acc[fb.src] > _EPS) & (acc[fb.dst] > _EPS)
+        nic, rtt, ecn = _nic_update(cfg, carry.nic, qmean, probe_ok, t,
+                                    stack)
 
     # ---- packet-loss stall + completion ----
-    stalled = ((offered > 1e-9) & (achieved_pp <= 1e-9)).any(1)
-    achieved = jnp.where(stalled, 0.0, achieved_pp.sum(1))
+    with jax.named_scope("slot/complete"):
+        stalled = ((offered > 1e-9) & (achieved_pp <= 1e-9)).any(1)
+        achieved = jnp.where(stalled, 0.0, achieved_pp.sum(1))
 
-    remaining = carry.remaining - achieved
-    newly = (~carry.done) & (remaining <= 0)
-    w = jnp.maximum(offered, _EPS)
-    qdelay = (((rtt * w).sum(1) / w.sum(1)) - cfg.base_rtt_us) \
-        / cfg.slot_us
-    completion = jnp.where(
-        newly, t + jnp.ceil(qdelay).astype(carry.completion.dtype),
-        carry.completion)
-    done = carry.done | newly
+        remaining = carry.remaining - achieved
+        newly = (~carry.done) & (remaining <= 0)
+        w = jnp.maximum(offered, _EPS)
+        qdelay = (((rtt * w).sum(1) / w.sum(1)) - cfg.base_rtt_us) \
+            / cfg.slot_us
+        completion = jnp.where(
+            newly, t + jnp.ceil(qdelay).astype(carry.completion.dtype),
+            carry.completion)
+        done = carry.done | newly
 
-    # ---- post-warmup accumulation (replaces dense (T, F) recording) ----
-    r = cfg.record_every
-    n_rec = (cfg.slots + r - 1) // r
-    w0 = int(n_rec * cfg.warmup_frac)
-    rec = (t % r) == 0
-    if n_rec > w0:
-        counted = rec & ((t // r) >= w0)
-    else:
-        counted = rec
-    goodput_sum = carry.goodput_sum + jnp.where(counted, achieved, 0.0)
+        # ---- post-warmup accumulation (replaces dense (T, F) recording) ----
+        r = cfg.record_every
+        n_rec = (cfg.slots + r - 1) // r
+        w0 = int(n_rec * cfg.warmup_frac)
+        rec = (t % r) == 0
+        if n_rec > w0:
+            counted = rec & ((t // r) >= w0)
+        else:
+            counted = rec
+        goodput_sum = carry.goodput_sum + jnp.where(counted, achieved, 0.0)
+        total = achieved.sum()
 
     new_carry = SimCarry(
         q_up=q_up, q_down=q_down, q2_up=q2_up, q2_down=q2_down,
@@ -1111,8 +1030,8 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
     extras = (bh,) if cfg.react else ()
     if not cfg.trace.enabled:
         if not cfg.react:
-            return new_carry, achieved.sum()
-        return new_carry, (achieved.sum(),) + extras
+            return new_carry, total
+        return new_carry, (total,) + extras
     # Trace outputs ride the scan's stacked ys (never the donated
     # carry); decimation happens in `_simulate`.  Padded flows offer
     # zero, so their host_bw contribution is exactly zero and the
@@ -1126,7 +1045,7 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
         "ecn": lambda: ecn,
         "eligible": lambda: nic.eligible,
     }
-    return new_carry, ((achieved.sum(),) + extras +
+    return new_carry, ((total,) + extras +
                        tuple(sig[f]() for f in cfg.trace.active_fields()))
 
 
@@ -1308,6 +1227,7 @@ def _jitted_mb(cfg: JxConfig, n_shards: int = 1,
 # entry points
 # ---------------------------------------------------------------------------
 
+_F32_LOCK = threading.Lock()
 _F32_WARNED: set = set()
 _F32_OVERFLOWS: List[Dict] = []
 
@@ -1323,7 +1243,7 @@ def f32_overflow_log() -> Tuple[Dict, ...]:
     in detection order — `{"spec": name, "max_bytes": float}` each.
     Executors slice this by length to attach the overflows of one run
     to its flight record."""
-    with _STATS_LOCK:
+    with _F32_LOCK:
         return tuple(dict(d) for d in _F32_OVERFLOWS)
 
 
@@ -1339,7 +1259,7 @@ def _warn_f32_bytes(name: str, fa: FlowArrays, stacklevel: int = 3
            "bytes tracking will stall and transfers may never "
            "complete — enable x64 (JAX_ENABLE_X64=1) or rescale "
            "bytes_total")
-    with _STATS_LOCK:
+    with _F32_LOCK:
         _F32_OVERFLOWS.append(
             {"spec": name, "max_bytes": float(finite.max())})
         first = name not in _F32_WARNED

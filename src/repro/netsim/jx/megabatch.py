@@ -56,6 +56,7 @@ import jax
 import numpy as np
 
 from repro.netsim.fabric import FlowArrays
+from repro.netsim.flight import count, span
 from repro.trace import FLOW_AXIS_FIELDS
 
 from repro.scenarios.spec import reaction_lag
@@ -125,15 +126,27 @@ def _struct_cfg(compiled) -> JxConfig:
     return cfg
 
 
+def _memo(caches: Dict, key: Tuple, name: str, build):
+    """`caches[key]`, built under the span `name` on a miss."""
+    value = caches.get(key)
+    if value is not None:
+        return value
+    with span(name):
+        value = caches[key] = build()
+    return value
+
+
 def _prepare(index: int, compiled, caches: Dict) -> _Point:
     cfg = _struct_cfg(compiled)
     spec = compiled.spec
     fa_key = (spec.topo, spec.tenants, spec.workloads, spec.workload_seed)
-    fa = caches.get(("fa", fa_key))
-    if fa is None:
+
+    def flow_arrays():
         fa = FlowArrays.build(compiled.flows, compiled.topo)
-        engine._warn_f32_bytes(spec.name, fa, stacklevel=5)
-        caches[("fa", fa_key)] = fa
+        engine._warn_f32_bytes(spec.name, fa, stacklevel=7)
+        return fa
+    fa = _memo(caches, ("fa", fa_key), "repro.prep.flow_arrays",
+               flow_arrays)
     pm = getattr(compiled, "phase_mult", None)
     # phase-change slots join the segment boundaries, so the timeline
     # memo key folds them in ((0,) for every non-schedule point —
@@ -147,8 +160,8 @@ def _prepare(index: int, compiled, caches: Dict) -> _Point:
     # reaction is off — existing sharing untouched)
     tl_key = (spec.faults, spec.sim.slots, spec.topo, spec.workload_seed,
               pb, lag)
-    cached = caches.get(("tl", tl_key))
-    if cached is None:
+
+    def timeline():
         tl = compile_fault_timeline(spec)
         vtl = None
         if react:
@@ -157,31 +170,27 @@ def _prepare(index: int, compiled, caches: Dict) -> _Point:
         if vtl is not None:
             boundaries |= set(vtl.change_slots())
         boundaries = tuple(sorted(boundaries))
-        cached = (tl, boundaries, engine._seg_caps(tl, boundaries),
-                  engine._vis_seg_caps(vtl, boundaries, cfg.n_planes),
-                  vtl)
-        caches[("tl", tl_key)] = cached
-    tl, boundaries, caps, vcaps, vtl = cached
+        return (tl, boundaries, engine._seg_caps(tl, boundaries),
+                engine._vis_seg_caps(vtl, boundaries, cfg.n_planes), vtl)
+    tl, boundaries, caps, vcaps, vtl = _memo(
+        caches, ("tl", tl_key), "repro.prep.timeline", timeline)
     routing, nic = spec.sim.routing, spec.sim.nic
     mode = r.mode if react else "instant"
     assign_key = assign = None
     if routing == "ecmp":
         assign_key = (fa_key, tl_key, compiled.cfg.seed, mode)
-        assign = caches.get(("assign", assign_key))
-        if assign is None:
-            assign = engine._assign_for(
+        assign = _memo(
+            caches, ("assign", assign_key), "repro.prep.ecmp_replay",
+            lambda: engine._assign_for(
                 replace(cfg, routing="ecmp"), fa, tl, compiled.cfg.seed,
                 boundaries, vtl=vtl, mode=mode,
-                backup=getattr(compiled, "backup", None))
-            caches[("assign", assign_key)] = assign
-    wkey = ("widths", fa_key, assign_key)
-    widths = caches.get(wkey)
-    if widths is None:
-        widths = engine._agg_widths(
+                backup=getattr(compiled, "backup", None)))
+    widths = _memo(
+        caches, ("widths", fa_key, assign_key), "repro.prep.widths",
+        lambda: engine._agg_widths(
             replace(cfg, routing=routing), fa,
             assign if assign is not None
-            else np.zeros((1, len(fa), cfg.n_planes), np.int32))
-        caches[wkey] = widths
+            else np.zeros((1, len(fa), cfg.n_planes), np.int32)))
     return _Point(index=index, cfg=cfg, routing=routing, nic=nic,
                   fa_key=fa_key, tl_key=tl_key, assign_key=assign_key,
                   fa=fa, boundaries=boundaries, caps=caps, assign=assign,
@@ -412,17 +421,28 @@ def _assemble_group(cfg: JxConfig, pts: List[_Point], caches: Dict,
 def _dispatch_group(cfg: JxConfig, pts: List[_Point], caches: Dict,
                     n_devices: Optional[int] = None):
     """Launch one structural group as a single program (see
-    `_assemble_group`)."""
-    shards, lanes, args, metas = _assemble_group(cfg, pts, caches,
-                                                 n_devices)
-    engine._record_launch("mega", (cfg, shards, lanes), args)
-    with warnings.catch_warnings():
-        # the scan rewrites the whole donated carry, but only 4 of its
-        # leaves alias a program output — jax warns about the rest on
-        # every first compile, which is expected here, not actionable
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        out = engine._jitted_mb(cfg, shards, lanes)(*args)
+    `_assemble_group`).  Counts the launch's flow-slots, real
+    (`flow_slots_real`: real flows of real rows) and launched
+    (`flow_slots_launched`: the flow bucket over every batch row, lane
+    pad replicas included), and the operand bytes it hands the device
+    (`launch_bytes`)."""
+    with span("repro.launch"):
+        shards, lanes, args, metas = _assemble_group(cfg, pts, caches,
+                                                     n_devices)
+        engine._record_launch("mega", (cfg, shards, lanes), args)
+        with warnings.catch_warnings():
+            # the scan rewrites the whole donated carry, but only 4 of
+            # its leaves alias a program output — jax warns about the
+            # rest on every first compile, which is expected here, not
+            # actionable
+            warnings.filterwarnings(
+                "ignore", message="Some donated buffers were not usable")
+            out = engine._jitted_mb(cfg, shards, lanes)(*args)
+    F_b = args[2].demand.shape[1]
+    count("flow_slots_real",
+          cfg.slots * sum(len(fa) for index, fa in metas if index >= 0))
+    count("flow_slots_launched", cfg.slots * len(metas) * F_b)
+    count("launch_bytes", sum(a.nbytes for a in jax.tree.leaves(args)))
     return cfg, metas, [p.index for p in pts], shards, out
 
 
@@ -437,12 +457,13 @@ def plan_megabatch(points: List) -> Tuple[Dict, List[List[Tuple]]]:
     caches: Dict = {}
     groups: Dict[Tuple, List[Tuple]] = {}
     order: List[Tuple] = []
-    for i, c in enumerate(points):
-        key = (_struct_cfg(c), _bucket(len(c.flows), FLOW_BUCKET_MIN))
-        if key not in groups:
-            groups[key] = []
-            order.append(key)
-        groups[key].append((i, c))
+    with span("repro.plan"):
+        for i, c in enumerate(points):
+            key = (_struct_cfg(c), _bucket(len(c.flows), FLOW_BUCKET_MIN))
+            if key not in groups:
+                groups[key] = []
+                order.append(key)
+            groups[key].append((i, c))
     return caches, [groups[k] for k in order]
 
 
@@ -451,7 +472,10 @@ def _sub_groups(group: List[Tuple], caches: Dict
     """Memoized `_prepare` of every member, sub-split by the complete
     structural key (fault-timeline segment counts only become known
     here)."""
-    prepared = [_prepare(i, c, caches) for i, c in group]
+    prepared = []
+    for i, c in group:
+        with span("repro.prep.point"):
+            prepared.append(_prepare(i, c, caches))
     sub: Dict[Tuple, List[_Point]] = {}
     order: List[Tuple] = []
     for p in prepared:
@@ -470,9 +494,10 @@ def dispatch_planned(group: List[Tuple], caches: Dict,
     complete structural key.  `n_devices` caps the lane mesh (default:
     every visible device).  Returns `[(point_indices, handle)]` entries
     for `finalize_group`."""
-    return [([p.index for p in pts],
-             _dispatch_group(cfg, pts, caches, n_devices))
-            for cfg, pts in _sub_groups(group, caches)]
+    with span("repro.dispatch"):
+        return [([p.index for p in pts],
+                 _dispatch_group(cfg, pts, caches, n_devices))
+                for cfg, pts in _sub_groups(group, caches)]
 
 
 def megabatch_programs(points: List, n_devices: Optional[int] = None
@@ -508,10 +533,22 @@ def dispatch_megabatch(points: List,
 
 
 def finalize_group(handle) -> List[JxSimResult]:
-    """Block on one `_dispatch_group` handle and unpack per-point
-    results, dropping lane padding and flow-bucket padding and undoing
-    the lane sort (results come back in the group's point order)."""
+    """Block on one `_dispatch_group` handle (span
+    `repro.finalize.wait`) and unpack its per-point results
+    (`repro.finalize.unpack`)."""
     cfg, metas, order, shards, out = handle
+    with span("repro.finalize"):
+        with span("repro.finalize.wait"):
+            jax.block_until_ready(out)
+        with span("repro.finalize.unpack"):
+            return _unpack(cfg, metas, order, out)
+
+
+def _unpack(cfg: JxConfig, metas: List[Tuple], order: List[int],
+            out) -> List[JxSimResult]:
+    """Per-point results of a finished launch, dropping lane padding and
+    flow-bucket padding and undoing the lane sort (results come back in
+    the group's point order)."""
     outs = [np.asarray(o) for o in out]
     by_index = {}
     for b, (index, fa) in enumerate(metas):

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.core.fault_tolerance import poisson_flaps
 from repro.netsim.fabric import Flow
+from repro.netsim.flight import span
 from repro.netsim.sim import SimConfig, SimResult, run_sim
 from repro.netsim.topology import (Fabric, FatTree, LeafSpine,
                                    backup_path_table)
@@ -464,7 +465,9 @@ def compile_scenario(spec: ScenarioSpec) -> CompiledScenario:
     topo = build_topology(spec.topo)
     rng = np.random.default_rng(spec.workload_seed)
     tenants = resolve_tenants(spec, rng)
-    flows, phase_mult, schedules = build_flows(spec, topo, tenants, rng)
+    with span("repro.prep.flows"):
+        flows, phase_mult, schedules = build_flows(spec, topo, tenants,
+                                                   rng)
     if not flows:
         raise ValueError(f"{spec.name}: scenario compiled to zero flows")
     events, fault_slots = make_events(spec)
