@@ -37,7 +37,9 @@ fused queue/ECN/NIC control update (`queue_ecn.queue_update` /
 `queue_ecn.nic_update`): a Pallas kernel on TPU (or under
 `REPRO_NETSIM_PALLAS=1`, interpret mode off-TPU), and otherwise a jnp
 fallback (`kernels/ref.py`) that is bit-identical to the historical
-engine math.
+engine math.  On a TPU, leaf-spine ECMP looks up each flow's path links
+by an exact one-hot contraction on the MXU instead of point gathers
+(`ecmp_onehot`).
 
 Flow aggregation has two modes (`JxConfig.agg_mode`): **dense** gathers
 flows into padded per-link bucket matrices (fast at registry shapes,
@@ -125,6 +127,25 @@ def compact_carry_default() -> bool:
     carry (int8 probe counters; x64 parity runs always keep wide
     state)."""
     return bool(_env_flag("REPRO_JX_COMPACT"))
+
+
+# a v5e's HBM
+_HBM_BYTES = 16 * 10**9
+
+
+def ecmp_onehot_budget(platform: str) -> int:
+    """Bytes that one `(F, L)` bfloat16 one-hot of the ECMP link lookups
+    may take on `platform`, or 0 to keep the point gathers (see
+    `ecmp_onehot`).  On a TPU v5e the gather unit serves `_route_ecmp`'s
+    per-flow point gathers at about 12 ns an element while the MXU
+    idles, and a one-hot contraction gives the same values exactly
+    (`_onehot_lookup`).  XLA CPU runs one-hot matmuls about 10x slower
+    than gathers, so every other platform keeps the gathers.  A batch
+    element may hold two one-hots (source and destination leaf) through
+    the whole scan, and each may take 1/64 of a v5e's 16 GB: F·L·2 bytes
+    up to 250 MB.  131,072 flows on 256 leaves take 64 MB; the guard
+    binds from about 490,000 flows on 256 leaves."""
+    return _HBM_BYTES // 64 if platform == "tpu" else 0
 
 
 # per-flow working-set arrays live per (flow, plane) cell in the chunked
@@ -220,6 +241,10 @@ class JxConfig:
     # only shape the operand *values*), so a mode × detect sweep shares
     # one compiled program per bucket.
     react: bool = False
+    # Leaf-spine ECMP: the largest `(F, L)` bfloat16 one-hot, in bytes,
+    # that the per-flow link lookups contract against on the MXU; 0
+    # keeps the point gathers (`ecmp_onehot_budget`, `ecmp_onehot`).
+    ecmp_onehot_bytes: int = 0
     # Participates in every jit-cache key / launch fingerprint, so the
     # default (disabled) spec leaves program identity — and the HLO —
     # exactly as if tracing did not exist.
@@ -269,7 +294,18 @@ class JxConfig:
                 topo.n_hosts, topo.n_leaves,
                 topo.n_cores if fat else topo.n_spines, topo.n_planes),
             compact_carry=compact_carry_default(),
+            ecmp_onehot_bytes=ecmp_onehot_budget(jax.default_backend()),
             trace=getattr(cfg, "trace", TraceSpec()))
+
+
+def ecmp_onehot(cfg: JxConfig, n_flows: int) -> bool:
+    """Whether `_route_ecmp` looks its links up by one-hot contraction
+    for a batch element of `n_flows` flows: a leaf-spine fabric, the
+    monolithic flow axis, and an `(F, L)` bfloat16 one-hot within
+    `cfg.ecmp_onehot_bytes`.  Decided from static shapes alone, at trace
+    time."""
+    return (cfg.kind == "leaf_spine" and not cfg.flow_chunk
+            and 0 < n_flows * cfg.n_leaves * 2 <= cfg.ecmp_onehot_bytes)
 
 
 @dataclass
@@ -652,19 +688,99 @@ def _route_pair(cfg: JxConfig, carry: SimCarry, fabric_rate: jnp.ndarray,
     return load_up, load_down, through, qmean, bh
 
 
+def _leaf_onehots(cfg: JxConfig, fb: FlowBatch
+                  ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """(F, L) bfloat16 one-hots of every flow's source and destination
+    leaf.  Leaves are static per flow, so they are built outside the
+    scan; XLA's TPU compiler may instead fold them into each slot's
+    contraction, where they cost a compare per element and no memory."""
+    leaves = jnp.arange(cfg.n_leaves)
+    return tuple((leaf[:, None] == leaves).astype(jnp.bfloat16)
+                 for leaf in (fb.src_leaf, fb.dst_leaf))
+
+
+def _exact_pieces(t: jnp.ndarray) -> List[jnp.ndarray]:
+    """Pieces of a table whose sum, first to last, is `t` exactly.  A
+    float32 table splits into three bfloat16 pieces, each the top 8
+    significant bits of what the pieces before it left.  The bits are
+    cut by a mask, not rounded, so neither a rounding mode nor a
+    compiler's excess precision can change a piece; three pieces hold
+    all 24 bits of a finite float32 whose nonzero magnitude is at least
+    2**-100 (below that a piece would be subnormal, which the MXU
+    flushes).  A float64 table (x64 parity runs, CPU) stays one piece."""
+    if t.dtype != jnp.float32:
+        return [t]
+    pieces = []
+    for _ in range(3):
+        bits = jax.lax.bitcast_convert_type(t, jnp.uint32)
+        head = jax.lax.bitcast_convert_type(
+            bits & jnp.uint32(0xFFFF0000), jnp.float32)
+        pieces.append(head.astype(jnp.bfloat16))
+        t = t - head
+    return pieces
+
+
+def _onehot_lookup(onehot: jnp.ndarray, tabs: jnp.ndarray,
+                   assign: jnp.ndarray) -> jnp.ndarray:
+    """`tabs[p, k, leaf[f], assign[f, p]]` as (K, F, P), from the (F, L)
+    one-hot of each flow's leaf and leaf-major (P, K, L, S) tables.  One
+    matmul of the one-hot against the tables' `_exact_pieces` side by
+    side gives every flow its leaf's rows, (F, pieces·P·K·S); a
+    select-and-sum over S picks the flow's spine, and the pieces add up
+    in order.  A one-hot row has a single 1, so every product but one is
+    an exact zero and each sum has one nonzero term: the result equals
+    the point gather bit for bit, but for a -0.0 entry, which reads
+    +0.0.  That needs finite tables (0 × inf is NaN): the engine's are
+    bottleneck fractions in [0, 1] (`bottleneck_ref`), queues in [0,
+    q_cap] (`queue_update_ref`) and link capacities."""
+    P, K, L, S = tabs.shape
+    pieces = _exact_pieces(tabs.transpose(2, 0, 1, 3).reshape(L, -1))
+    rows = jnp.dot(onehot, jnp.concatenate(pieces, 1),
+                   preferred_element_type=tabs.dtype)
+    rows = rows.reshape(-1, len(pieces), P, K, S)
+    pick = assign[:, None, :, None, None] == jnp.arange(S)
+    picked = jnp.where(pick, rows, 0.0).sum(-1)          # (F, n, P, K)
+    out = picked[:, 0]
+    for i in range(1, len(pieces)):
+        out = out + picked[:, i]
+    return jnp.moveaxis(out, -1, 0)
+
+
+def _link_values(fb: FlowBatch, assign: jnp.ndarray,
+                 ups: Tuple[jnp.ndarray, ...],
+                 downs: Tuple[jnp.ndarray, ...],
+                 onehots: Optional[Tuple[jnp.ndarray, jnp.ndarray]]
+                 ) -> Tuple[List[jnp.ndarray], List[jnp.ndarray]]:
+    """Each link table's value on every flow's ECMP path, (F, P) each:
+    `t[p, src_leaf, assign[:, p]]` of the (P, L, S) uplink tables `ups`,
+    `t[p, assign[:, p], dst_leaf]` of the (P, S, L) downlink tables
+    `downs`.  Point gathers, or with the `_leaf_onehots` the exact
+    contraction of `_onehot_lookup` (see `ecmp_onehot`)."""
+    if onehots is None:
+        F, P = assign.shape
+        p_iota = jnp.arange(P)[None, :].repeat(F, 0)
+        return ([t[p_iota, fb.src_leaf[:, None], assign] for t in ups],
+                [t[p_iota, assign, fb.dst_leaf[:, None]] for t in downs])
+    src, dst = onehots
+    return (list(_onehot_lookup(src, jnp.stack(ups, 1), assign)),
+            list(_onehot_lookup(dst, jnp.stack(downs, 1).swapaxes(2, 3),
+                                assign)))
+
+
 def _route_ecmp(cfg: JxConfig, carry: SimCarry, fabric_rate: jnp.ndarray,
                 up: jnp.ndarray, down: jnp.ndarray, fb: FlowBatch,
                 assign_segments: jnp.ndarray, load_fn: Callable,
-                seg: jnp.ndarray):
+                seg: jnp.ndarray,
+                onehots: Optional[Tuple[jnp.ndarray, jnp.ndarray]]):
     """ECMP: one-hot spine choice from the precomputed assignment
     segment, loads via padded bucket sums.  `load_fn(seg)` yields the
     (P, LS+SL, C) permutation plan for the current capacity segment —
     a slice of this element's `_AggPerms.ecmp_load` on the per-group
     path, a row of the batch-deduplicated plan table on the megabatch
-    path."""
+    path.  `onehots` (`_leaf_onehots`, or None for point gathers)
+    select how each flow reads its path's links (`_link_values`)."""
     P, L, S = cfg.n_planes, cfg.n_leaves, cfg.n_spines
     assign = assign_segments[seg]                         # (F, P)
-    p_iota = jnp.arange(P)[None, :].repeat(fabric_rate.shape[0], 0)
     if cfg.agg_mode == "sparse":
         pk = jnp.arange(P)[None, :]
         k_up = pk * (L * S) + fb.src_leaf[:, None] * S + assign
@@ -687,18 +803,18 @@ def _route_ecmp(cfg: JxConfig, carry: SimCarry, fabric_rate: jnp.ndarray,
         load_down = loads[:, L * S:].reshape(P, S, L)
         f_up = fracs[:, :L * S].reshape(P, L, S)
         f_down = fracs[:, L * S:].reshape(P, S, L)
-    scale_f = jnp.minimum(
-        f_up[p_iota, fb.src_leaf[:, None], assign],
-        f_down[p_iota, assign, fb.dst_leaf[:, None]])
-    through = fabric_rate * scale_f
-    qmean = (carry.q_up[p_iota, fb.src_leaf[:, None], assign] +
-             carry.q_down[p_iota, assign, fb.dst_leaf[:, None]])
+    # the physical capacities are looked up only under reaction
+    n = 3 if cfg.react else 2
+    (fu, qu, *cu), (fd, qd, *cd) = _link_values(
+        fb, assign, (f_up, carry.q_up, up)[:n],
+        (f_down, carry.q_down, down)[:n], onehots)
+    through = fabric_rate * jnp.minimum(fu, fd)
+    qmean = qu + qd
     if not cfg.react:
         return load_up, load_down, through, qmean
     # blackholed bytes: the one-hot assignment (already steered by the
     # lagged view on the host) landing on a physically dead path
-    capF = jnp.minimum(up[p_iota, fb.src_leaf[:, None], assign],
-                       down[p_iota, assign, fb.dst_leaf[:, None]])
+    capF = jnp.minimum(cu[0], cd[0])
     bh = (fabric_rate * (capF <= _EPS)).sum()
     return load_up, load_down, through, qmean, bh
 
@@ -870,6 +986,7 @@ def _route_ecmp_ft(cfg: JxConfig, carry: SimCarry,
 
 
 def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
+               onehots: Optional[Tuple[jnp.ndarray, jnp.ndarray]],
                aggs: _AggPerms, assign_segments: jnp.ndarray,
                seg_up: jnp.ndarray, seg_down: jnp.ndarray,
                seg_acc: jnp.ndarray, seg_up2: jnp.ndarray,
@@ -912,8 +1029,9 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
     # ---- link loads + per-flow fabric throughput/queue, without any
     # (F, P, J) load intermediate: AR/WAR fractions are leaf-pair
     # quantities, so flows aggregate to (P, L, L) before touching the
-    # path axis; ECMP's one-hot path choice reduces to (F, P) gathers +
-    # padded bucket sums.  The topology kind is static, so the branch
+    # path axis; ECMP's one-hot path choice reduces to (F, P) link
+    # lookups (point gathers, or one-hot contractions on a TPU) + padded
+    # bucket sums.  The topology kind is static, so the branch
     # list holds that kind's pair/ecmp implementations (fat-tree ones
     # also return stage-B loads); under traced dispatch `lax.switch`
     # evaluates both branches for the whole batch and selects per
@@ -932,7 +1050,7 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
                 partial(_route_pair, cfg, carry, fabric_rate, up, down,
                         upv, downv, aggs, pair_idx, use_war),
                 partial(_route_ecmp, cfg, carry, fabric_rate, up, down,
-                        fb, assign_segments, load_fn, seg)]
+                        fb, assign_segments, load_fn, seg, onehots)]
         if stack is None:
             routed = branches[1 if cfg.routing == "ecmp" else 0]()
         elif isinstance(stack.route, int):
@@ -1072,14 +1190,16 @@ def _simulate(cfg: JxConfig, fb: FlowBatch, seg_up, seg_down, seg_acc,
         def load_fn(seg):
             return ecmp_table[uid, seg]
     pair_idx = fb.src_leaf * cfg.n_leaves + fb.dst_leaf
+    onehots = (_leaf_onehots(cfg, fb)
+               if ecmp_onehot(cfg, fb.src.shape[0]) else None)
     xs = (jnp.arange(cfg.slots), seg_id)
-    step = partial(_slot_step, cfg, fb, pair_idx, aggs, assign_segments,
-                   jnp.asarray(seg_up), jnp.asarray(seg_down),
-                   jnp.asarray(seg_acc), jnp.asarray(seg_up2),
-                   jnp.asarray(seg_down2), jnp.asarray(seg_dem),
-                   jnp.asarray(seg_vup), jnp.asarray(seg_vdown),
-                   jnp.asarray(seg_vup2), jnp.asarray(seg_vdown2),
-                   stack, load_fn)
+    step = partial(_slot_step, cfg, fb, pair_idx, onehots, aggs,
+                   assign_segments, jnp.asarray(seg_up),
+                   jnp.asarray(seg_down), jnp.asarray(seg_acc),
+                   jnp.asarray(seg_up2), jnp.asarray(seg_down2),
+                   jnp.asarray(seg_dem), jnp.asarray(seg_vup),
+                   jnp.asarray(seg_vdown), jnp.asarray(seg_vup2),
+                   jnp.asarray(seg_vdown2), stack, load_fn)
     carry, ys = jax.lax.scan(step, carry0, xs)
     # ys layout: raw scalar (no trace, no react) | tuple of
     # (total, [blackhole], *trace-fields) — blackhole stays full-rate

@@ -424,8 +424,10 @@ def _dispatch_group(cfg: JxConfig, pts: List[_Point], caches: Dict,
     `_assemble_group`).  Counts the launch's flow-slots, real
     (`flow_slots_real`: real flows of real rows) and launched
     (`flow_slots_launched`: the flow bucket over every batch row, lane
-    pad replicas included), and the operand bytes it hands the device
-    (`launch_bytes`)."""
+    pad replicas included), the launched flow-slots of ECMP rows whose
+    link lookups contract one-hots (`ecmp_onehot_flow_slots`, 0 where
+    they gather; see `engine.ecmp_onehot`), and the operand bytes it
+    hands the device (`launch_bytes`)."""
     with span("repro.launch"):
         shards, lanes, args, metas = _assemble_group(cfg, pts, caches,
                                                      n_devices)
@@ -442,6 +444,11 @@ def _dispatch_group(cfg: JxConfig, pts: List[_Point], caches: Dict,
     count("flow_slots_real",
           cfg.slots * sum(len(fa) for index, fa in metas if index >= 0))
     count("flow_slots_launched", cfg.slots * len(metas) * F_b)
+    ecmp_rows = shards * sum(n for route, n in lanes
+                             if route == engine.ROUTE_ECMP)
+    count("ecmp_onehot_flow_slots",
+          cfg.slots * ecmp_rows * F_b
+          if engine.ecmp_onehot(cfg, F_b) else 0)
     count("launch_bytes", sum(a.nbytes for a in jax.tree.leaves(args)))
     return cfg, metas, [p.index for p in pts], shards, out
 

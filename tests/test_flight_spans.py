@@ -134,6 +134,26 @@ def test_flow_slot_counters_count_bucket_padding():
     assert "points" not in fl                # no amortized per-point wall
 
 
+@pytest.mark.parametrize("contract", [True, False],
+                         ids=["onehot", "gather"])
+def test_ecmp_onehot_counter_counts_contracted_ecmp_flow_slots(
+        monkeypatch, contract):
+    from repro.netsim.jx import megabatch
+
+    if contract:
+        struct_cfg = megabatch._struct_cfg
+        monkeypatch.setattr(megabatch, "_struct_cfg", lambda c: replace(
+            struct_cfg(c), ecmp_onehot_bytes=1 << 30))
+    points = _grid(slots=20)                 # two ECMP points, one AR
+    F_b = _bucket(len(compile_scenario(points[0]).flows), FLOW_BUCKET_MIN)
+    fl, pair = {}, {}
+    execute_points(points, flight=fl)
+    execute_points(points[2:], flight=pair)
+    assert fl["counters"]["ecmp_onehot_flow_slots"] == \
+        (20 * F_b * 2 if contract else 0)
+    assert pair["counters"]["ecmp_onehot_flow_slots"] == 0
+
+
 def test_compile_on_a_worker_thread_is_credited_to_the_caller():
     flight.watch_compiles()
     n = 5 + next(_uniq) + 7 * (threading.get_ident() % 97)

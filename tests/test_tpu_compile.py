@@ -14,7 +14,9 @@ under pytest-xdist only the worker given this file loads it.
 """
 import functools
 import os
+import re
 import warnings
+from dataclasses import replace
 
 import jax
 import jax.numpy as jnp
@@ -180,6 +182,66 @@ def test_megabatch_program_compiles_with_kernels(one_chip, monkeypatch):
             "ignore", message="Some donated buffers were not usable")
         compiled = fn.lower(*avals).compile()
     assert compiled.as_text().count("tpu_custom_call") > 0
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < DEVICE_BYTES
+
+
+_DEF = re.compile(r"^\s*(?:ROOT )?(%[\w.\-]+) = \w+\[([\d,]*)\]")
+_GATHER = re.compile(r" gather\((%[\w.\-]+), (%[\w.\-]+)\)")
+
+
+def _point_gathers(text, rows):
+    """Gathers of the compiled program whose index operand holds `rows`
+    index vectors of three or more components: per-flow point lookups
+    of a (plane, leaf, spine) table, batch coordinate included."""
+    dims = {}
+    for line in text.splitlines():
+        m = _DEF.match(line)
+        if m:
+            dims[m.group(1)] = [int(d) for d in m.group(2).split(",") if d]
+    out = []
+    for line in text.splitlines():
+        m = _GATHER.search(line)
+        idx = dims.get(m.group(2)) if m else None
+        if idx and idx[-1] >= 3 and np.prod(idx[:-1]) == rows:
+            out.append(line.strip()[:160])
+    return out
+
+
+@pytest.mark.parametrize("scenario,contract", [
+    ("giga_fabric_storage", True), ("flap_during_incast", False)])
+def test_ecmp_program_contracts_its_link_lookups(one_chip, monkeypatch,
+                                                 scenario, contract):
+    """An ECMP point as one megabatch program, compiled for the chip with
+    the link lookups it selects there.  The giga point's one-hot
+    contraction compiles, fits, and leaves no per-flow point gather of a
+    link table; a program that keeps the gathers holds four of them."""
+    from repro.netsim.jx import engine, megabatch
+    from repro.scenarios import compile_scenario, get_scenario
+
+    monkeypatch.setattr(engine, "_JIT_CACHE", {})
+    budget = engine.ecmp_onehot_budget("tpu" if contract else "cpu")
+    struct_cfg = megabatch._struct_cfg
+    monkeypatch.setattr(megabatch, "_struct_cfg", lambda c: replace(
+        struct_cfg(c), ecmp_onehot_bytes=budget))
+    compiled_spec = compile_scenario(get_scenario(scenario).with_sim(
+        routing="ecmp", backend="jax"))
+    (fn, args), = megabatch.megabatch_programs([compiled_spec],
+                                               n_devices=1)
+    F, P = args[2].src.shape[1], _dims(scenario)["P"]
+    assert engine.ecmp_onehot(megabatch._struct_cfg(compiled_spec),
+                              F) == contract
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            np.shape(a), jax.dtypes.canonicalize_dtype(np.asarray(a).dtype),
+            sharding=one_chip), args)
+    with warnings.catch_warnings():
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
+        compiled = fn.lower(*avals).compile()
+    assert len(_point_gathers(compiled.as_text(), F * P)) == \
+        (0 if contract else 4)
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < DEVICE_BYTES
