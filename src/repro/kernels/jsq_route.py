@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .ref import jsq_bin
+
 BIG = 1e30
 
 
@@ -47,8 +49,7 @@ def _pair_score_kernel(q_ref, cap_ref, w_ref, out_ref, *, nbins: int,
     cap = cap_ref[...].astype(jnp.float32)
     w = w_ref[...].astype(jnp.float32)
     up = cap > 1e-9
-    qbin = jnp.floor(jnp.clip(q / qmax, 0.0, 1.0 - 1e-9) * nbins) + 1.0
-    score = qbin / jnp.maximum(w, 1e-9)
+    score = jsq_bin(q, nbins=nbins, qmax=qmax) / jnp.maximum(w, 1e-9)
     logit = jnp.where(up, -score / temperature, -BIG)
     logit -= jnp.max(logit, axis=-1, keepdims=True)
     e = jnp.exp(logit)

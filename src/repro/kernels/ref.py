@@ -111,6 +111,16 @@ def plane_split_ref(rate, eligible, demand, *, mode: str,
     return jnp.minimum(demand[:, None] * w, jnp.where(elig, rate, 0.0))
 
 
+def jsq_bin(q, *, nbins: int, qmax: float) -> jax.Array:
+    """Quantized queue depth: bins 1..nbins over [0, qmax], a queue at
+    or past `qmax` in the top one.  The top bin is a cap: `q / qmax`
+    clipped to 1 - 1e-9 instead would be 1.0 in float32 and score a
+    saturated path nbins + 1.  In float64 both forms give the same bin
+    (`FluidFabric._jsq_softmax`)."""
+    return jnp.minimum(jnp.floor(jnp.clip(q / qmax, 0.0, 1.0) * nbins),
+                       nbins - 1) + 1.0
+
+
 def pair_score_softmax_ref(q, cap, w, *, nbins: int, temperature: float,
                            qmax: float = 8.0) -> jax.Array:
     """Quantized-JSQ spine scoring + softmax over the trailing spine
@@ -118,8 +128,7 @@ def pair_score_softmax_ref(q, cap, w, *, nbins: int, temperature: float,
     fluid twin).  `q`/`cap`/`w`: (..., S) summed pair queue, path
     capacity, and path weight; returns (..., S) spine fractions."""
     up_mask = cap > 1e-9
-    qbin = jnp.floor(jnp.clip(q / qmax, 0, 1 - 1e-9) * nbins) + 1.0
-    score = qbin / jnp.maximum(w, 1e-9)
+    score = jsq_bin(q, nbins=nbins, qmax=qmax) / jnp.maximum(w, 1e-9)
     logit = jnp.where(up_mask, -score / temperature, -1e30)
     logit -= logit.max(-1, keepdims=True)
     e = jnp.exp(logit)

@@ -426,8 +426,10 @@ def _dispatch_group(cfg: JxConfig, pts: List[_Point], caches: Dict,
     (`flow_slots_launched`: the flow bucket over every batch row, lane
     pad replicas included), the launched flow-slots of ECMP rows whose
     link lookups contract one-hots (`ecmp_onehot_flow_slots`, 0 where
-    they gather; see `engine.ecmp_onehot`), and the operand bytes it
-    hands the device (`launch_bytes`)."""
+    they gather; see `engine.ecmp_onehot`), the launched flow-slots of
+    rows that route by leaf pair, ar or war (`pair_route_flow_slots`,
+    from each row's own routing), and the operand bytes it hands the
+    device (`launch_bytes`)."""
     with span("repro.launch"):
         shards, lanes, args, metas = _assemble_group(cfg, pts, caches,
                                                      n_devices)
@@ -449,6 +451,8 @@ def _dispatch_group(cfg: JxConfig, pts: List[_Point], caches: Dict,
     count("ecmp_onehot_flow_slots",
           cfg.slots * ecmp_rows * F_b
           if engine.ecmp_onehot(cfg, F_b) else 0)
+    count("pair_route_flow_slots", cfg.slots * F_b * int(
+        np.count_nonzero(args[0].route == engine.ROUTE_PAIR)))
     count("launch_bytes", sum(a.nbytes for a in jax.tree.leaves(args)))
     return cfg, metas, [p.index for p in pts], shards, out
 

@@ -154,6 +154,22 @@ def test_ecmp_onehot_counter_counts_contracted_ecmp_flow_slots(
     assert pair["counters"]["ecmp_onehot_flow_slots"] == 0
 
 
+def test_pair_route_counter_counts_ar_and_war_flow_slots():
+    """`pair_route_flow_slots` counts the launched flow-slots of rows
+    that route by leaf pair: none of an ECMP launch, two rows of three
+    in a mixed ar/war/ecmp launch."""
+    ecmp = _grid(slots=20)[0]
+    only, mixed = {}, {}
+    execute_points([ecmp], flight=only)
+    execute_points([ecmp.with_sim(routing=r) for r in ("ar", "war", "ecmp")],
+                   flight=mixed)
+    assert only["counters"]["pair_route_flow_slots"] == 0
+    c = mixed["counters"]
+    F_b = _bucket(len(compile_scenario(ecmp).flows), FLOW_BUCKET_MIN)
+    assert c["flow_slots_launched"] == 3 * 20 * F_b
+    assert c["pair_route_flow_slots"] == 2 * 20 * F_b
+
+
 def test_compile_on_a_worker_thread_is_credited_to_the_caller():
     flight.watch_compiles()
     n = 5 + next(_uniq) + 7 * (threading.get_ident() % 97)

@@ -80,6 +80,20 @@ def _compile(fn, sharding, *shapes):
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _compile_program(fn, args, sharding):
+    """Compile a megabatch program for the described chip from the
+    shapes of its operands."""
+    avals = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            np.shape(a), jax.dtypes.canonicalize_dtype(np.asarray(a).dtype),
+            sharding=sharding), args)
+    with warnings.catch_warnings():
+        # the scan rewrites the donated carry; not every leaf aliases
+        warnings.filterwarnings(
+            "ignore", message="Some donated buffers were not usable")
+        return fn.lower(*avals).compile()
+
+
 f32, b1 = jnp.float32, jnp.bool_
 _NIC_KW = dict(base_rtt_us=4.0, slot_us=10.0, ecn_thresh=4.0,
                target_rtt_us=12.0, min_rate=0.01, md=0.7, ai=0.08,
@@ -172,15 +186,7 @@ def test_megabatch_program_compiles_with_kernels(one_chip, monkeypatch):
                                              backend="jax"))
               for r in ("ar", "war", "ecmp") for n in ("spx", "dcqcn")]
     (fn, args), = megabatch_programs(points, n_devices=1)
-    avals = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(
-            np.shape(a), jax.dtypes.canonicalize_dtype(np.asarray(a).dtype),
-            sharding=one_chip), args)
-    with warnings.catch_warnings():
-        # the scan rewrites the donated carry; not every leaf aliases
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        compiled = fn.lower(*avals).compile()
+    compiled = _compile_program(fn, args, one_chip)
     assert compiled.as_text().count("tpu_custom_call") > 0
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
@@ -232,16 +238,45 @@ def test_ecmp_program_contracts_its_link_lookups(one_chip, monkeypatch,
     F, P = args[2].src.shape[1], _dims(scenario)["P"]
     assert engine.ecmp_onehot(megabatch._struct_cfg(compiled_spec),
                               F) == contract
-    avals = jax.tree.map(
-        lambda a: jax.ShapeDtypeStruct(
-            np.shape(a), jax.dtypes.canonicalize_dtype(np.asarray(a).dtype),
-            sharding=one_chip), args)
-    with warnings.catch_warnings():
-        warnings.filterwarnings(
-            "ignore", message="Some donated buffers were not usable")
-        compiled = fn.lower(*avals).compile()
+    compiled = _compile_program(fn, args, one_chip)
     assert len(_point_gathers(compiled.as_text(), F * P)) == \
         (0 if contract else 4)
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < DEVICE_BYTES
+
+
+_MXU_OP = re.compile(r" (?:dot|convolution)\(")
+
+
+def test_war_program_keeps_its_pair_loads_in_float32(one_chip, monkeypatch):
+    """The giga point under weighted AR as one megabatch program, the
+    Pallas kernels compiled inside its scan: it compiles and fits the
+    chip, and its pair-load contractions (`_route_pair`'s two einsums)
+    become float32 multiply-reduce fusions.  No dot of the route stage
+    reaches the MXU, where default precision would round the operands
+    to bfloat16."""
+    from repro.kernels import backend
+    from repro.netsim.jx import engine
+    from repro.netsim.jx.megabatch import megabatch_programs
+    from repro.scenarios import compile_scenario, get_scenario
+
+    monkeypatch.setenv("REPRO_NETSIM_PALLAS", "1")
+    monkeypatch.setattr(backend, "pallas_interpret",
+                        lambda override=None: False)
+    monkeypatch.setattr(engine, "_JIT_CACHE", {})
+    spec = compile_scenario(get_scenario("giga_fabric_storage").with_sim(
+        routing="war", backend="jax"))
+    (fn, args), = megabatch_programs([spec], n_devices=1)
+    compiled = _compile_program(fn, args, one_chip)
+    text = compiled.as_text()
+    assert "pair_fractions" in text and "tpu_custom_call" in text
+    loads = [line for line in text.splitlines()
+             if "slot/route/plm,plms->" in line and "reduce(" in line]
+    assert len(loads) == 2
+    assert all("= f32[" in line for line in loads)
+    assert not [line for line in text.splitlines()
+                if _MXU_OP.search(line) and "slot/route" in line]
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < DEVICE_BYTES
