@@ -39,7 +39,9 @@ fused queue/ECN/NIC control update (`queue_ecn.queue_update` /
 fallback (`kernels/ref.py`) that is bit-identical to the historical
 engine math.  On a TPU, leaf-spine ECMP looks up each flow's path links
 by an exact one-hot contraction on the MXU instead of point gathers
-(`ecmp_onehot`).
+(`ecmp_onehot`), and the sparse path sums its ECMP link loads and
+host-port loads by contracting the same leaf one-hots instead of
+scatter-adding (`onehot_agg`).
 
 Flow aggregation has two modes (`JxConfig.agg_mode`): **dense** gathers
 flows into padded per-link bucket matrices (fast at registry shapes,
@@ -306,6 +308,20 @@ def ecmp_onehot(cfg: JxConfig, n_flows: int) -> bool:
     time."""
     return (cfg.kind == "leaf_spine" and not cfg.flow_chunk
             and 0 < n_flows * cfg.n_leaves * 2 <= cfg.ecmp_onehot_bytes)
+
+
+def onehot_agg(cfg: JxConfig, n_flows: int) -> bool:
+    """Whether a batch element of `n_flows` flows sums its sparse link
+    loads (ECMP) and host-port loads by contracting the same leaf
+    one-hots on the MXU (`_leaf_sum`) instead of scatter-adding
+    (`segment_load`): the one-hots' own rule (`ecmp_onehot`), sparse
+    aggregation, and a float32 program (under x64 the scatter keeps the
+    NumPy engine's flow order).  On a TPU v5e the scatter-adds run at
+    about 2 ms a megabyte of operand, while the contraction's products
+    are exact and each bucket stays a float32 sum.  Decided from static
+    shapes and the platform alone, at trace time."""
+    return (cfg.agg_mode == "sparse" and not jax.config.jax_enable_x64
+            and ecmp_onehot(cfg, n_flows))
 
 
 @dataclass
@@ -593,14 +609,25 @@ def _seg_sum(vals: jnp.ndarray, perm: jnp.ndarray) -> jnp.ndarray:
 
 
 def _host_sum(cfg: JxConfig, vals: jnp.ndarray, idx: jnp.ndarray,
-              perm: jnp.ndarray) -> jnp.ndarray:
+              perm: jnp.ndarray,
+              onehot: Optional[jnp.ndarray] = None) -> jnp.ndarray:
     """(F, P) per-flow values summed into (H, P) per-host buckets:
     gather-plan sum (dense) or a (host, plane)-keyed `segment_load`
     (sparse — the row-major flatten scatters in flow order, so XLA CPU
-    f64 stays bit-equal to the NumPy engine's `np.add.at`)."""
+    f64 stays bit-equal to the NumPy engine's `np.add.at`).  With the
+    (F, L) one-hot of the hosts' leaves (`onehot_agg`), each flow's value
+    goes to the column of its host's place on the leaf, and `_leaf_sum`
+    adds them up by leaf: host `h` is place `h % HPL` of leaf `h // HPL`,
+    HPL hosts a leaf."""
     if cfg.agg_mode != "sparse":
         return _seg_sum(vals, perm)
     P = vals.shape[1]
+    if onehot is not None:
+        hpl = cfg.n_hosts // cfg.n_leaves
+        place = (idx % hpl)[:, None, None] == jnp.arange(hpl)[:, None]
+        v = jnp.where(place, vals[:, None, :], 0.0)          # (F, HPL, P)
+        return _leaf_sum(onehot, v.reshape(-1, hpl * P)).reshape(
+            cfg.n_hosts, P)
     keys = idx[:, None] * P + jnp.arange(P)[None, :]
     return segment_load(vals, keys, cfg.n_hosts * P).reshape(
         cfg.n_hosts, P)
@@ -720,6 +747,25 @@ def _exact_pieces(t: jnp.ndarray) -> List[jnp.ndarray]:
     return pieces
 
 
+def _leaf_sum(onehot: jnp.ndarray, vals: jnp.ndarray) -> jnp.ndarray:
+    """(F, N) per-flow values summed by the leaf of the (F, L) one-hot,
+    (L, N): one matmul of the one-hot's transpose against the values'
+    `_exact_pieces` side by side, the pieces' sums added first to last.
+    Each product is exact (0 or 1 times a bfloat16 piece), so a bucket
+    is a float32 sum of float32 terms, as a scatter-add's is, in another
+    order: the same where the sums are exact, within rounding elsewhere.
+    Terms below 2**-100 flush (`_exact_pieces`), and the values must be
+    finite (0 × inf is NaN): the engine's are rates, pad flows' +0.0."""
+    pieces = _exact_pieces(vals)
+    sums = jnp.dot(onehot.T, jnp.concatenate(pieces, 1),
+                   preferred_element_type=vals.dtype)
+    sums = sums.reshape(sums.shape[0], len(pieces), -1)
+    out = sums[:, 0]
+    for i in range(1, len(pieces)):
+        out = out + sums[:, i]
+    return out
+
+
 def _onehot_lookup(onehot: jnp.ndarray, tabs: jnp.ndarray,
                    assign: jnp.ndarray) -> jnp.ndarray:
     """`tabs[p, k, leaf[f], assign[f, p]]` as (K, F, P), from the (F, L)
@@ -767,6 +813,29 @@ def _link_values(fb: FlowBatch, assign: jnp.ndarray,
                                 assign)))
 
 
+def _ecmp_link_loads(cfg: JxConfig, fb: FlowBatch, assign: jnp.ndarray,
+                     rate: jnp.ndarray,
+                     onehots: Optional[Tuple[jnp.ndarray, jnp.ndarray]]
+                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Sparse ECMP link loads, (P, L, S) up and (P, S, L) down, of the
+    (F, P) `rate` on each flow's `assign`ed spines: (plane, link)-keyed
+    `segment_load`s, or where `onehot_agg` holds, `_leaf_sum`s of each
+    flow's rate in the column of its (plane, spine) by its source and
+    by its destination leaf (`onehots`)."""
+    P, L, S = cfg.n_planes, cfg.n_leaves, cfg.n_spines
+    F = assign.shape[0]
+    if onehots is not None and onehot_agg(cfg, F):
+        spine = assign[:, :, None] == jnp.arange(S)       # (F, P, S)
+        v = jnp.where(spine, rate[:, :, None], 0.0).reshape(F, P * S)
+        up, down = (_leaf_sum(oh, v).reshape(L, P, S) for oh in onehots)
+        return up.transpose(1, 0, 2), down.transpose(1, 2, 0)
+    pk = jnp.arange(P)[None, :]
+    k_up = pk * (L * S) + fb.src_leaf[:, None] * S + assign
+    k_dn = pk * (S * L) + assign * L + fb.dst_leaf[:, None]
+    return (segment_load(rate, k_up, P * L * S).reshape(P, L, S),
+            segment_load(rate, k_dn, P * S * L).reshape(P, S, L))
+
+
 def _route_ecmp(cfg: JxConfig, carry: SimCarry, fabric_rate: jnp.ndarray,
                 up: jnp.ndarray, down: jnp.ndarray, fb: FlowBatch,
                 assign_segments: jnp.ndarray, load_fn: Callable,
@@ -778,17 +847,13 @@ def _route_ecmp(cfg: JxConfig, carry: SimCarry, fabric_rate: jnp.ndarray,
     a slice of this element's `_AggPerms.ecmp_load` on the per-group
     path, a row of the batch-deduplicated plan table on the megabatch
     path.  `onehots` (`_leaf_onehots`, or None for point gathers)
-    select how each flow reads its path's links (`_link_values`)."""
+    select how each flow reads its path's links (`_link_values`) and
+    how the sparse loads add up (`_ecmp_link_loads`)."""
     P, L, S = cfg.n_planes, cfg.n_leaves, cfg.n_spines
     assign = assign_segments[seg]                         # (F, P)
     if cfg.agg_mode == "sparse":
-        pk = jnp.arange(P)[None, :]
-        k_up = pk * (L * S) + fb.src_leaf[:, None] * S + assign
-        k_dn = pk * (S * L) + assign * L + fb.dst_leaf[:, None]
-        load_up = segment_load(fabric_rate, k_up,
-                               P * L * S).reshape(P, L, S)
-        load_down = segment_load(fabric_rate, k_dn,
-                                 P * S * L).reshape(P, S, L)
+        load_up, load_down = _ecmp_link_loads(cfg, fb, assign,
+                                              fabric_rate, onehots)
         f_up, f_down = _bottleneck(cfg, up, down, load_up, load_down)
     else:
         padT = jnp.concatenate(
@@ -1068,8 +1133,11 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
             load_up, load_down, through, qmean = routed
 
     with jax.named_scope("slot/host_load"):
-        load_acc_tx = _host_sum(cfg, offered, fb.src, aggs.src)  # (H, P)
-        load_acc_rx = _host_sum(cfg, offered, fb.dst, aggs.dst)
+        src_oh, dst_oh = (onehots if onehot_agg(cfg, fb.src.shape[0])
+                          else (None, None))
+        load_acc_tx = _host_sum(cfg, offered, fb.src, aggs.src,
+                                src_oh)                       # (H, P)
+        load_acc_rx = _host_sum(cfg, offered, fb.dst, aggs.dst, dst_oh)
 
     # ---- bottleneck scaling (access; fabric scaling lives in the
     # routing branches) ----
@@ -1157,7 +1225,7 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
     sig = {
         "host_bw": lambda: _host_sum(
             cfg, jnp.where(stalled[:, None], 0.0, achieved_pp), fb.src,
-            aggs.src),
+            aggs.src, src_oh),
         "util": lambda: util,
         "queue": lambda: q_up,
         "ecn": lambda: ecn,

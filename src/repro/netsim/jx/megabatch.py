@@ -427,7 +427,9 @@ def _dispatch_group(cfg: JxConfig, pts: List[_Point], caches: Dict,
     pad replicas included), the launched flow-slots of ECMP rows whose
     link lookups contract one-hots (`ecmp_onehot_flow_slots`, 0 where
     they gather; see `engine.ecmp_onehot`), the launched flow-slots of
-    rows that route by leaf pair, ar or war (`pair_route_flow_slots`,
+    rows whose host-port sums contract one-hots (`onehot_agg_flow_slots`,
+    every row or none; see `engine.onehot_agg`), the launched flow-slots
+    of rows that route by leaf pair, ar or war (`pair_route_flow_slots`,
     from each row's own routing), and the operand bytes it hands the
     device (`launch_bytes`)."""
     with span("repro.launch"):
@@ -451,6 +453,8 @@ def _dispatch_group(cfg: JxConfig, pts: List[_Point], caches: Dict,
     count("ecmp_onehot_flow_slots",
           cfg.slots * ecmp_rows * F_b
           if engine.ecmp_onehot(cfg, F_b) else 0)
+    count("onehot_agg_flow_slots",
+          cfg.slots * len(metas) * F_b if engine.onehot_agg(cfg, F_b) else 0)
     count("pair_route_flow_slots", cfg.slots * F_b * int(
         np.count_nonzero(args[0].route == engine.ROUTE_PAIR)))
     count("launch_bytes", sum(a.nbytes for a in jax.tree.leaves(args)))

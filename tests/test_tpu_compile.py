@@ -280,3 +280,43 @@ def test_war_program_keeps_its_pair_loads_in_float32(one_chip, monkeypatch):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes) < DEVICE_BYTES
+
+
+_SCATTER = re.compile(r"= \w+\[([\d,]*)\]\S* scatter\(")
+_SCATTER_SCOPE = re.compile(r'op_name="[^"]*/(slot/\w+)/scatter-add"')
+
+
+@pytest.mark.parametrize("routing", ["ecmp", "war"])
+def test_giga_program_sums_by_contraction_not_scatter(one_chip, monkeypatch,
+                                                      routing):
+    """The giga point as one megabatch program, compiled for the chip with
+    the sums it selects there (`engine.onehot_agg`): the ECMP program
+    keeps no scatter-add in its route and host-load stages, and the WAR
+    program keeps one, in its route stage, of `P·L·L` buckets: the
+    leaf-pair sum.  A compiled scatter names no stage itself; the ops
+    that feed its updates do."""
+    from repro.netsim.jx import engine, megabatch
+    from repro.scenarios import compile_scenario, get_scenario
+
+    monkeypatch.setattr(engine, "_JIT_CACHE", {})
+    struct_cfg = megabatch._struct_cfg
+    monkeypatch.setattr(megabatch, "_struct_cfg", lambda c: replace(
+        struct_cfg(c), ecmp_onehot_bytes=engine.ecmp_onehot_budget("tpu")))
+    spec = compile_scenario(get_scenario("giga_fabric_storage").with_sim(
+        routing=routing, backend="jax"))
+    (fn, args), = megabatch.megabatch_programs([spec], n_devices=1)
+    d = _dims("giga_fabric_storage")
+    assert engine.onehot_agg(megabatch._struct_cfg(spec), d["F"])
+    compiled = _compile_program(fn, args, one_chip)
+    text = compiled.as_text()
+    shapes = _SCATTER.findall(text)
+    scopes = set(_SCATTER_SCOPE.findall(text))
+    assert "slot/host_load" not in scopes
+    if routing == "ecmp":
+        assert not shapes and "slot/route" not in scopes
+    else:
+        assert shapes == [str(d["P"] * d["L"] * d["L"])]
+        assert scopes == {"slot/route"}
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes) < DEVICE_BYTES
