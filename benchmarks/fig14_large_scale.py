@@ -94,8 +94,7 @@ def run_giga(slots: int = 0) -> None:
         spec = spec.with_sim(slots=slots)
     pristine = replace(spec, faults=())
     t0 = time.perf_counter()
-    out = execute_points([pristine, spec], backend="jax",
-                         jx_dispatch="megabatch")
+    out = execute_points([pristine, spec], backend="jax")
     wall = time.perf_counter() - t0
     g0, gk = out[0].mean_goodput, out[1].mean_goodput
     emit("fig14c.giga_sim.k8_random_kill", wall * 1e6,
@@ -132,7 +131,7 @@ def run_giga_full(slots: int = 0, seeds=(0, 1, 2),
                 (replace(spec.faults[0], count=k),)))
     flight = {}
     t0 = time.perf_counter()
-    out = execute_points(points, backend="jax", jx_dispatch="megabatch",
+    out = execute_points(points, backend="jax",
                          flight=flight)
     wall = time.perf_counter() - t0
     by_k = {}

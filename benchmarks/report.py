@@ -1,7 +1,6 @@
 """Markdown report generator for EXPERIMENTS.md §Dry-run / §Roofline,
-plus an observability section from the committed backend-bench snapshot
-(`BENCH_backend.json`) and an optional ResultSet's flight-recorder
-stats.
+plus an observability section from an optional ResultSet's
+flight-recorder stats.
 
   PYTHONPATH=src python -m benchmarks.report > experiments/report.md
   PYTHONPATH=src python -m benchmarks.report --resultset sweep.json
@@ -9,13 +8,7 @@ stats.
 from __future__ import annotations
 
 import argparse
-import json
-import os
-import sys
 
-import numpy as np
-
-from .backend_bench import BASELINE_PATH
 from .roofline import load_cells, roofline_row
 
 
@@ -25,54 +18,31 @@ def fmt_bytes(b) -> str:
     return f"{b / (1 << 30):.2f}"
 
 
-def print_observability(bench_path: str = BASELINE_PATH,
-                        resultset_path: str | None = None) -> None:
-    """The in-tree perf trajectory (committed bench snapshot) and, when
-    a ResultSet JSON is given, its flight-recorder executor stats."""
+def print_observability(resultset_path: str | None = None) -> None:
+    """A ResultSet JSON's flight-recorder executor stats, when one is
+    given."""
     print("\n## Observability\n")
-    if os.path.exists(bench_path):
-        b = json.load(open(bench_path, encoding="utf-8"))
-        g = b["grid"]
-        print(f"Committed backend bench ({g['scenario']}, "
-              f"{g['points']} points x {g['slots']} slots, "
-              f"{b['devices']} device(s)):\n")
-        print("| path | warm s | warm slots/s | dispatches | compiles |")
-        print("|---|---|---|---|---|")
-        np_row = b.get("numpy_pool")
-        if np_row:
-            print(f"| numpy_pool | {np_row['warm_s']:.3f} | "
-                  f"{np_row['slots_per_s']:.0f} | - | - |")
-        for key in ("per_group", "megabatch"):
-            r = b.get(key)
-            if r:
-                print(f"| {key} | {r['warm_s']:.3f} | "
-                      f"{r['warm_slots_per_s']:.0f} | "
-                      f"{r['dispatches']} | {r['compiles']} |")
-        print(f"\n- megabatch vs per-group warm: "
-              f"{b['speedup_warm_vs_per_group']:.2f}x; peak RSS "
-              f"{b['peak_rss_bytes'] / (1 << 20):.0f} MiB")
-    else:
-        print(f"- no committed bench snapshot at {bench_path}")
-    if resultset_path:
-        from repro.experiments import ResultSet
+    if not resultset_path:
+        print("- no ResultSet given (--resultset)")
+        return
+    from repro.experiments import ResultSet
 
-        rs = ResultSet.from_json(
-            open(resultset_path, encoding="utf-8").read())
-        fl = rs.flight
-        if not fl:
-            print(f"\n- {resultset_path}: no flight-recorder data")
-            return
-        print(f"\nFlight recorder ({resultset_path}, "
-              f"experiment {fl.get('experiment')!r}): "
-              f"{fl.get('cache_hits', 0)} cache hits, "
-              f"{fl.get('cache_misses', 0)} misses\n")
-        print("| backend | mode | points | wall s | dispatch stats |")
-        print("|---|---|---|---|---|")
-        for ex in fl.get("executions", ()):
-            stats = ex.get("dispatch_stats")
-            print(f"| {ex.get('backend')} | {ex.get('mode')} | "
-                  f"{ex.get('n_points')} | {ex.get('wall_s', 0.0):.3f} | "
-                  f"{stats if stats else '-'} |")
+    rs = ResultSet.from_json(open(resultset_path, encoding="utf-8").read())
+    fl = rs.flight
+    if not fl:
+        print(f"- {resultset_path}: no flight-recorder data")
+        return
+    print(f"Flight recorder ({resultset_path}, "
+          f"experiment {fl.get('experiment')!r}): "
+          f"{fl.get('cache_hits', 0)} cache hits, "
+          f"{fl.get('cache_misses', 0)} misses\n")
+    print("| backend | mode | points | wall s | dispatch stats |")
+    print("|---|---|---|---|---|")
+    for ex in fl.get("executions", ()):
+        stats = ex.get("dispatch_stats")
+        print(f"| {ex.get('backend')} | {ex.get('mode')} | "
+              f"{ex.get('n_points')} | {ex.get('wall_s', 0.0):.3f} | "
+              f"{stats if stats else '-'} |")
 
 
 def main() -> None:

@@ -9,9 +9,9 @@ dispatch that `run_experiment` uses — in float32, with the engine's
 Pallas kernels compiled by Mosaic, and is checked against the NumPy
 engine (the reference):
 
-  (a) the acceptance grid of `benchmarks/backend_bench.py` on
-      `flap_during_incast`: 3 routings x 2 NIC stacks x 3 fault
-      fractions x 2 seeds = 36 points in one launch and one compile;
+  (a) the acceptance grid on `flap_during_incast`: 3 routings x 2 NIC
+      stacks x 3 fault fractions x 2 seeds = 36 points in one launch
+      and one compile;
   (b) `giga_fabric_storage` at registry size: 4,096 hosts, 102,400
       flows, 60 slots, 8 link kills (sparse aggregation);
   (c) the `train_step_flap` schedule workload (phase-timeline operands).
@@ -204,10 +204,16 @@ def report(phase: str, **facts) -> None:
 
 
 def grid_points():
-    from benchmarks.backend_bench import bench_grid
+    """The acceptance grid on `flap_during_incast`: routing × nic ×
+    fault-frac (rescaling the scenario's first fault) × seed."""
+    from repro.experiments import Axis, Experiment, product
 
-    exp = bench_grid("flap_during_incast", ("ar", "war", "ecmp"),
-                     ("spx", "dcqcn"), (0.3, 0.5, 0.8), 2, None)
+    axes = product(Axis("sim.routing", ("ar", "war", "ecmp")),
+                   Axis("sim.nic", ("spx", "dcqcn")),
+                   Axis("faults[0].frac", (0.3, 0.5, 0.8)),
+                   Axis("seed", (0, 1)))
+    exp = Experiment(name="chip_smoke.grid", base="flap_during_incast",
+                     axes=axes)
     points = [p.spec for p in exp.points()]
     if len(points) != 36:
         raise SystemExit(f"grid: expected 36 points, got {len(points)}")

@@ -2,18 +2,15 @@
 `sweep`/`sweep_many` shims.
 
 'numpy' fans points out over a process pool; 'jax' dispatches the whole
-grid through the megabatch path by default — every structurally
-compatible point (any mix of routing / nic / fault / seed axes) stacks
-into ONE fused `jit(vmap)` launch (mesh-sharded over multiple devices)
-that compiles once (`repro.netsim.jx.megabatch`), with host prep of
-bucket k+1 pipelined against device execution of bucket k — or, with
-`jx_dispatch="group"`, through the legacy per-(scenario, routing, nic)
-grouped-vmap path.  Either way
-completed rows stream back through `on_result(index, metrics)` as they
-finish — per future on the pool path, per finalized batch/group on the
-JAX paths — which is what lets `run_experiment` write the cache and
-fill the `ResultSet` incrementally instead of all-or-nothing at the
-end.
+grid through the megabatch path: every structurally compatible point
+(any mix of routing / nic / fault / seed axes) stacks into ONE fused
+`jit(vmap)` launch (mesh-sharded over multiple devices) that compiles
+once (`repro.netsim.jx.megabatch`), with host prep of bucket k+1
+pipelined against device execution of bucket k.  Either way completed
+rows stream back through `on_result(index, metrics)` as they finish —
+per future on the pool path, per finalized bucket on the JAX path —
+which is what lets `run_experiment` write the cache and fill the
+`ResultSet` incrementally instead of all-or-nothing at the end.
 
 Every JAX sweep keeps JAX's persistent compilation cache on
 (`enable_compile_cache`), so the megabatch program (one compile per
@@ -42,8 +39,6 @@ from repro.scenarios.runner import ScenarioMetrics, distill_metrics, run_point
 from repro.scenarios.spec import ScenarioSpec
 
 OnResult = Callable[[int, ScenarioMetrics], None]
-
-JX_DISPATCH_MODES = ("megabatch", "group")
 
 # the compile cache's home when neither JAX_COMPILATION_CACHE_DIR nor an
 # explicit directory names one: fixed (the path is part of every cache
@@ -107,18 +102,15 @@ def execute_points(points: List[ScenarioSpec],
                    backend: Optional[str] = None,
                    derive: Optional[Callable] = None,
                    on_result: Optional[OnResult] = None,
-                   jx_dispatch: Optional[str] = None,
                    compile_cache_dir: Optional[str] = None,
                    flight: Optional[Dict] = None
                    ) -> List[ScenarioMetrics]:
     """Run every point; returns metrics in point order.  `backend=None`
     inherits the specs' `sim.backend` (which must agree — mixed grids
     are partitioned by the caller).  `on_result` fires once per point as
-    it completes, *before* the call returns.  `jx_dispatch` picks the
-    JAX dispatch path ('megabatch' default, 'group' = the legacy
-    per-structure batching; `REPRO_JX_DISPATCH` overrides the default);
-    `compile_cache_dir` places the persistent compilation cache unless
-    `JAX_COMPILATION_CACHE_DIR` is set (see `enable_compile_cache`).
+    it completes, *before* the call returns.  `compile_cache_dir` places
+    the persistent compilation cache unless `JAX_COMPILATION_CACHE_DIR`
+    is set (see `enable_compile_cache`).
 
     The call is one sweep of the flight recorder
     (`repro.netsim.flight`): its host spans (`repro.execute` around the
@@ -142,8 +134,7 @@ def execute_points(points: List[ScenarioSpec],
         with span("repro.execute"):
             backend, out, summary = _execute(
                 points, processes, backend, derive,
-                on_result or (lambda i, m: None), jx_dispatch,
-                compile_cache_dir)
+                on_result or (lambda i, m: None), compile_cache_dir)
     if flight is not None:
         if backend == "jax":
             summary["dispatch_stats"] = rec.snapshot()
@@ -159,8 +150,7 @@ def execute_points(points: List[ScenarioSpec],
 
 def _execute(points: List[ScenarioSpec], processes: Optional[int],
              backend: Optional[str], derive: Optional[Callable],
-             emit: OnResult, jx_dispatch: Optional[str],
-             compile_cache_dir: Optional[str]):
+             emit: OnResult, compile_cache_dir: Optional[str]):
     """`execute_points` without its flight record: returns the resolved
     backend, the metrics in point order and the path's summary."""
     if backend is None:
@@ -172,14 +162,9 @@ def _execute(points: List[ScenarioSpec], processes: Optional[int],
         backend = inherited.pop() if inherited else "numpy"
     if backend == "jax":
         enable_compile_cache(compile_cache_dir)
-        mode = (jx_dispatch or
-                os.environ.get("REPRO_JX_DISPATCH", "megabatch"))
-        if mode not in JX_DISPATCH_MODES:
-            raise ValueError(
-                f"unknown jx_dispatch {mode!r}; expected one of "
-                f"{JX_DISPATCH_MODES}")
-        out, overflows, pipeline = _execute_jax(points, derive, emit, mode)
-        return backend, out, {"mode": mode, "f32_overflows": overflows,
+        out, overflows, pipeline = _execute_jax(points, derive, emit)
+        return backend, out, {"mode": "megabatch",
+                              "f32_overflows": overflows,
                               "pipeline": pipeline}
     if backend != "numpy":
         raise ValueError(
@@ -256,31 +241,24 @@ def _xla_backend_live() -> bool:
 
 
 def _execute_jax(points: List[ScenarioSpec], derive: Optional[Callable],
-                 emit: OnResult, mode: str = "megabatch"):
-    """Batched single-process sweep.
+                 emit: OnResult):
+    """Batched single-process sweep through the megabatch path.
 
-    'megabatch' (default): every structurally compatible point — any
-    mix of routing, nic, fault, and seed axes — stacks into ONE fused
-    `jit(vmap)` launch that compiles once; heterogeneous flow counts
-    and fault timelines share programs via shape buckets
-    (`repro.netsim.jx.megabatch`).  Dispatch is pipelined: a single
-    prep worker runs the memoized host prep + launch of shape bucket
-    k+1 while the device executes bucket k, and the main thread
-    finalizes each bucket's rows as it retires.
+    Every structurally compatible point — any mix of routing, nic,
+    fault, and seed axes — stacks into ONE fused `jit(vmap)` launch
+    that compiles once; heterogeneous flow counts and fault timelines
+    share programs via shape buckets (`repro.netsim.jx.megabatch`).
+    Dispatch is pipelined: a single prep worker runs the memoized host
+    prep + launch of shape bucket k+1 while the device executes bucket
+    k, and the main thread finalizes each bucket's rows as it retires.
+    Launches, spans and counters go to the caller's `collect_dispatch`
+    scope, which the prep worker adopts."""
+    import jax
 
-    'group' (the PR 3 path, kept for A/B benchmarking and parity
-    pinning): group grid points that share structure (same scenario
-    modulo the seeds) and run each group as its own `vmap` batch — one
-    compile and one launch per (scenario, routing, nic, fault)
-    structure.
-
-    Either way everything is dispatched before anything is awaited (JAX
-    CPU execution is async), with
-    `XLA_FLAGS=--xla_force_host_platform_device_count=N` sharding batch
-    axes over the N host devices, and completed rows stream out per
-    finalized batch.  Launches, spans and counters go to the caller's
-    `collect_dispatch` scope, which the prep worker adopts."""
+    from repro.netsim.flight import adopt_dispatch, current_collectors
     from repro.netsim.jx.engine import f32_overflow_log
+    from repro.netsim.jx.megabatch import (dispatch_planned, finalize_group,
+                                           plan_megabatch)
 
     results: List[Optional[ScenarioMetrics]] = [None] * len(points)
     n_overflows0 = len(f32_overflow_log())
@@ -297,68 +275,35 @@ def _execute_jax(points: List[ScenarioSpec], derive: Optional[Callable],
         with span("repro.scenario"):
             return compile_scenario(p)
 
-    pipeline: Dict = {}
-    if mode == "megabatch":
-        from repro.netsim.flight import adopt_dispatch, current_collectors
-        from repro.netsim.jx.megabatch import (dispatch_planned,
-                                               finalize_group,
-                                               plan_megabatch)
+    compiled = [compile_point(p) for p in points]
+    caches, planned = plan_megabatch(compiled)
+    collectors = current_collectors()
+    x64 = bool(jax.config.jax_enable_x64)
 
-        import jax
+    def prep(group):
+        # the worker thread runs outside the main thread's
+        # collect_dispatch scope AND its thread-local jax config
+        # overrides (`jax.enable_x64(...)` contexts): adopt the counters
+        # and re-assert the caller's x64 state so the launch traces with
+        # the caller's dtypes
+        with adopt_dispatch(collectors), jax.enable_x64(x64):
+            return dispatch_planned(group, caches)
 
-        compiled = [compile_point(p) for p in points]
-        caches, planned = plan_megabatch(compiled)
-        collectors = current_collectors()
-        x64 = bool(jax.config.jax_enable_x64)
-
-        def prep(group):
-            # the worker thread runs outside the main thread's
-            # collect_dispatch scope AND its thread-local jax
-            # config overrides (`jax.enable_x64(...)` contexts): adopt
-            # the counters and re-assert the caller's x64 state so the
-            # launch traces with the caller's dtypes
-            with adopt_dispatch(collectors), jax.enable_x64(x64):
-                return dispatch_planned(group, caches)
-
-        launches = 0
-        # single prep worker: host prep (memoized flow arrays,
-        # fault timelines, ECMP replays) of bucket k+1 overlaps
-        # device execution of bucket k (JAX dispatch is async);
-        # the main thread finalizes rows as buckets retire
-        with ThreadPoolExecutor(max_workers=1) as pool:
-            futs = [pool.submit(prep, g) for g in planned]
-            for fut in futs:
-                for idxs, handle in fut.result():
-                    launches += 1
-                    for i, r in zip(idxs, finalize_group(handle)):
-                        deliver(i, compiled[i], r)
-        # >1 launch means prep/execute/finalize actually overlapped
-        # (launch k+1's host prep runs while the device executes k)
-        pipeline = {"groups": len(planned), "launches": launches,
-                    "pipelined": launches > 1}
-    else:
-        from repro.netsim.jx.engine import (dispatch_compiled_batch,
-                                            finalize_batch)
-
-        order: List = []
-        groups: Dict = {}
-        for i, p in enumerate(points):
-            key = replace(p,
-                          sim=replace(p.sim, seed=0,
-                                      backend="numpy"),
-                          workload_seed=0)
-            if key not in groups:
-                groups[key] = []
-                order.append(key)
-            groups[key].append(i)
-        dispatched = []
-        for key in order:
-            idxs = groups[key]
-            compiled = [compile_point(points[i]) for i in idxs]
-            dispatched.append((idxs, compiled,
-                               dispatch_compiled_batch(compiled)))
-        for idxs, compiled, handle in dispatched:
-            for i, c, r in zip(idxs, compiled, finalize_batch(handle)):
-                deliver(i, c, r)
+    launches = 0
+    # single prep worker: host prep (memoized flow arrays, fault
+    # timelines, ECMP replays) of bucket k+1 overlaps device execution
+    # of bucket k (JAX dispatch is async); the main thread finalizes
+    # rows as buckets retire
+    with ThreadPoolExecutor(max_workers=1) as pool:
+        futs = [pool.submit(prep, g) for g in planned]
+        for fut in futs:
+            for idxs, handle in fut.result():
+                launches += 1
+                for i, r in zip(idxs, finalize_group(handle)):
+                    deliver(i, compiled[i], r)
     overflows = list(f32_overflow_log()[n_overflows0:])
-    return results, overflows, pipeline
+    # >1 launch means prep/execute/finalize actually overlapped (launch
+    # k+1's host prep runs while the device executes k)
+    return results, overflows, {"groups": len(planned),
+                                "launches": launches,
+                                "pipelined": launches > 1}

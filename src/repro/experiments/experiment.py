@@ -136,7 +136,6 @@ def run_experiment(exp: Experiment,
                    processes: Optional[int] = None,
                    backend: Optional[str] = None,
                    cache: Union[RunCache, str, None] = None,
-                   jx_dispatch: Optional[str] = None,
                    compile_cache_dir: Optional[str] = None
                    ) -> ResultSet:
     """Execute the experiment grid into a `ResultSet`.
@@ -147,11 +146,10 @@ def run_experiment(exp: Experiment,
     in-flight points, and the next call resumes from the survivors).
     `backend` pins every point ('numpy' | 'jax'); None runs each point
     on its spec's own `sim.backend`, so a `sim.backend` axis sweeps
-    both.  On the JAX backend `jx_dispatch` selects 'megabatch' (whole
-    grid fused into one launch per structure — the default) or 'group'
-    (legacy per-structure batching), and `compile_cache_dir` turns on
-    JAX's persistent compilation cache so the fused program survives
-    process restarts.  Rows come back in grid order; `rs.cache_hits` /
+    both.  On the JAX backend the whole grid fuses into one launch per
+    structure (megabatch), and `compile_cache_dir` places JAX's
+    persistent compilation cache, so the fused program survives process
+    restarts.  Rows come back in grid order; `rs.cache_hits` /
     `rs.cache_misses` report how the run was served."""
     if isinstance(cache, str):
         cache = RunCache(cache)
@@ -190,8 +188,7 @@ def run_experiment(exp: Experiment,
             fl: Dict = {}
             execute_points(
                 [p.spec for p in group], processes=processes, backend=bk,
-                derive=exp.derive, jx_dispatch=jx_dispatch,
-                compile_cache_dir=compile_cache_dir,
+                derive=exp.derive, compile_cache_dir=compile_cache_dir,
                 on_result=lambda j, m, g=group: on_result(g, j, m),
                 flight=fl)
             # executor point indices are group-local; lift to grid order

@@ -70,10 +70,9 @@ class DispatchCounter:
 
 
 def _stack():
-    stack = getattr(_COLLECTORS, "stack", None)
-    if stack is None:
-        stack = _COLLECTORS.stack = []
-    return stack
+    if not hasattr(_COLLECTORS, "stack"):
+        _COLLECTORS.stack = []
+    return _COLLECTORS.stack
 
 
 @contextmanager

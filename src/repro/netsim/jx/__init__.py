@@ -16,8 +16,7 @@ Parity: with x64 enabled (`JAX_ENABLE_X64=1` or
 """
 from .events import FaultTimeline, compile_fault_timeline, has_static_timeline
 from .engine import (JxConfig, JxSimResult, StackIdx, dispatch_stats,
-                     reset_dispatch_stats, run_compiled,
-                     run_compiled_batch)
+                     reset_dispatch_stats, run_compiled)
 from .megabatch import (dispatch_megabatch, dispatch_planned,
                         finalize_group, plan_megabatch, run_megabatch)
 from .state import FlowBatch, NicCarry, SimCarry
@@ -25,7 +24,7 @@ from .state import FlowBatch, NicCarry, SimCarry
 __all__ = [
     "FaultTimeline", "compile_fault_timeline", "has_static_timeline",
     "JxConfig", "JxSimResult", "StackIdx", "run_compiled",
-    "run_compiled_batch", "run_megabatch", "dispatch_megabatch",
+    "run_megabatch", "dispatch_megabatch",
     "plan_megabatch", "dispatch_planned", "finalize_group",
     "dispatch_stats", "reset_dispatch_stats",
     "FlowBatch", "NicCarry", "SimCarry",

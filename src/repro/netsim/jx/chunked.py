@@ -37,7 +37,6 @@ route branches per chunk would double the streaming cost).
 from __future__ import annotations
 
 from functools import partial
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -48,55 +47,12 @@ from repro.kernels.link_load import (bottleneck as _k_bottleneck,
 from repro.kernels.queue_ecn import queue_update as _k_queue_update
 
 from . import engine
-from .state import FlowBatch, NicCarry, SimCarry, init_carry
+from .state import FlowBatch, SimCarry
 
 _EPS = engine._EPS
 
 
-def _pad_flows(fb: FlowBatch, F_pad: int, slots: int) -> FlowBatch:
-    """Pad the flow axis to a chunk multiple with the megabatch's inert
-    pads: zero demand, infinite bytes, start beyond the horizon, and
-    `same_leaf` so they never touch the fabric."""
-    pad = F_pad - fb.src.shape[0]
-    if not pad:
-        return fb
-
-    def p(a, fill):
-        return jnp.concatenate([a, jnp.full((pad,), fill, a.dtype)])
-
-    return FlowBatch(
-        src=p(fb.src, 0), dst=p(fb.dst, 0),
-        src_leaf=p(fb.src_leaf, 0), dst_leaf=p(fb.dst_leaf, 0),
-        demand=p(fb.demand, 0.0),
-        bytes_total=p(fb.bytes_total, jnp.inf),
-        start_slot=p(fb.start_slot, slots),
-        same_leaf=p(fb.same_leaf, True),
-        phase=p(fb.phase, 0))
-
-
-def _pad_carry(carry: SimCarry, pad: int) -> SimCarry:
-    """Pad a caller-built carry's per-flow leaves to the chunk multiple
-    (no-op on the megabatch path, whose flow bucket is pre-rounded so
-    the donated buffers stay structurally usable)."""
-    if not pad:
-        return carry
-
-    def p(a, fill):
-        return jnp.concatenate(
-            [a, jnp.full((pad,) + a.shape[1:], fill, a.dtype)])
-
-    nic = NicCarry(
-        rate=p(carry.nic.rate, 1.0), alpha=p(carry.nic.alpha, 0.0),
-        probe_miss=p(carry.nic.probe_miss, 0),
-        eligible=p(carry.nic.eligible, True),
-        pending_fail=p(carry.nic.pending_fail, 0))
-    return carry._replace(
-        nic=nic, remaining=p(carry.remaining, jnp.inf),
-        done=p(carry.done, False), completion=p(carry.completion, -1),
-        goodput_sum=p(carry.goodput_sum, 0.0))
-
-
-def _slot_step_chunked(cfg, route, use_war, stack, fbc, assign_c, F_in,
+def _slot_step_chunked(cfg, route, use_war, stack, fbc, assign_c,
                        seg_up, seg_down, seg_acc, seg_up2, seg_down2,
                        seg_dem, seg_vup, seg_vdown, seg_vup2, seg_vdown2,
                        carry, xs):
@@ -326,7 +282,7 @@ def _slot_step_chunked(cfg, route, use_war, stack, fbc, assign_c, F_in,
     # ---- pass 2: stream chunks through the per-flow fabric gathers.
     # Only the gather-heavy delivery math stays inside the chunk scan;
     # the NIC / completion / goodput updates run once on the flat
-    # (F_pad, ...) results below, in the monolithic step's exact op
+    # (F, ...) results below, in the monolithic step's exact op
     # order — keeping those mul-add chains out of the scan body, where
     # XLA's small-shape codegen (scalar FMA contraction at chunk sizes
     # like 1) would cost the last ulp of x64 parity. ----
@@ -426,25 +382,21 @@ def _slot_step_chunked(cfg, route, use_war, stack, fbc, assign_c, F_in,
         q_up=q_up, q_down=q_down, q2_up=q2_up, q2_down=q2_down,
         nic=nic_new, remaining=remaining, done=done,
         completion=completion, goodput_sum=goodput_sum, util_up=util)
-    # totals reduce over the *incoming* flow count: the (F_in,) slice
-    # has the monolithic sum's exact shape, so the reduction tree — and
-    # with it x64 bit-parity — matches (pads would only append +0.0
-    # terms, but a wider shape alone can change the tree)
-    total = achieved[:F_in].sum()
+    total = achieved.sum()
     if not cfg.react:
         return new_carry, total
-    bh = bh_mid if pair_route else flat(ys2[4])[:F_in].sum()
+    bh = bh_mid if pair_route else flat(ys2[4]).sum()
     return new_carry, (total, bh)
 
 
-def simulate_chunked(cfg, fb: FlowBatch, seg_up, seg_down, seg_acc,
-                     seg_up2, seg_down2, seg_dem, seg_vup, seg_vdown,
-                     seg_vup2, seg_vdown2, assign_segments, seg_id,
-                     stack=None, carry0: Optional[SimCarry] = None):
+def simulate_chunked(cfg, stack: engine.StackIdx, carry0: SimCarry,
+                     fb: FlowBatch, seg_up, seg_down, seg_acc, seg_up2,
+                     seg_down2, seg_dem, seg_vup, seg_vdown, seg_vup2,
+                     seg_vdown2, assign_segments, seg_id):
     """`engine._simulate`'s streaming twin (`cfg.flow_chunk > 0`).
-    Same operands, same return contract (minus trace tails); dispatched
-    from inside `_simulate`, so every caller — per-group, grouped vmap,
-    megabatch lanes — streams transparently."""
+    Same operands (minus the gather plans), same return contract (minus
+    trace tails); dispatched from inside `_simulate`, so every megabatch
+    lane streams transparently."""
     ch = int(cfg.flow_chunk)
     if cfg.agg_mode != "sparse":
         raise ValueError(
@@ -453,28 +405,19 @@ def simulate_chunked(cfg, fb: FlowBatch, seg_up, seg_down, seg_acc,
     if cfg.trace.enabled:
         raise NotImplementedError(
             "flow_chunk does not compose with TraceSpec captures")
-    if stack is not None and not isinstance(stack.route, int):
+    if not isinstance(stack.route, int):
         raise NotImplementedError(
             "chunked streaming needs a concrete per-lane route index "
             "(megabatch lane-sorts elements); the per-element "
             "lax.switch fallback is unsupported")
-    route = (stack.route if stack is not None else
-             (engine.ROUTE_ECMP if cfg.routing == "ecmp"
-              else engine.ROUTE_PAIR))
-    use_war = cfg.routing == "war" if stack is None else stack.is_war
-    F_in = int(fb.src.shape[0])
-    F_pad = -(-F_in // ch) * ch
-    nc = F_pad // ch
-    fb = _pad_flows(fb, F_pad, cfg.slots)
-    if carry0 is None:
-        carry0 = init_carry(fb, cfg)
-    else:
-        carry0 = _pad_carry(carry0, F_pad - F_in)
+    route, use_war = stack.route, stack.is_war
+    F = int(fb.src.shape[0])
+    if F % ch:
+        raise ValueError(
+            f"{F} flows are not a multiple of the chunk length {ch} "
+            "(megabatch rounds its flow bucket up to one)")
+    nc = F // ch
     assign = jnp.asarray(assign_segments)
-    if assign.shape[1] < F_pad:
-        assign = jnp.concatenate(
-            [assign, jnp.zeros((assign.shape[0], F_pad - assign.shape[1],
-                                assign.shape[2]), assign.dtype)], axis=1)
     # chunk-major views, built once outside the scan: flow columns as
     # (nc, ch, ...), the assignment segments as (nc, n_seg, ch, P)
     fbc = FlowBatch(*[jnp.reshape(jnp.asarray(a),
@@ -483,7 +426,7 @@ def simulate_chunked(cfg, fb: FlowBatch, seg_up, seg_down, seg_acc,
     assign_c = jnp.moveaxis(
         assign.reshape(assign.shape[0], nc, ch, assign.shape[2]), 1, 0)
     step = partial(_slot_step_chunked, cfg, route, use_war, stack, fbc,
-                   assign_c, F_in,
+                   assign_c,
                    jnp.asarray(seg_up), jnp.asarray(seg_down),
                    jnp.asarray(seg_acc), jnp.asarray(seg_up2),
                    jnp.asarray(seg_down2), jnp.asarray(seg_dem),
@@ -500,5 +443,5 @@ def simulate_chunked(cfg, fb: FlowBatch, seg_up, seg_down, seg_acc,
     n_rec = (cfg.slots + r - 1) // r
     w0 = int(n_rec * cfg.warmup_frac)
     frames = (n_rec - w0) if n_rec > w0 else n_rec
-    return (carry.goodput_sum[:F_in] / frames, carry.completion[:F_in],
+    return (carry.goodput_sum / frames, carry.completion,
             totals, carry.util_up) + bh

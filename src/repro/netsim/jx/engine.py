@@ -9,8 +9,9 @@ reproduces, operation for operation, the NumPy pipeline:
   (`spx|dcqcn|global|esr|swlb`) -> loss-stall masking -> transfer
   completion.
 
-The loop runs under `lax.scan`; whole sweep axes (seeds, each with its own
-flow population and fault timeline) run as one `jax.vmap` batch.  Fault
+The loop runs under `lax.scan`; a whole grid (routing × nic × fault ×
+seed points, each with its own flow population and fault timeline) runs
+as one `jax.vmap` batch that `megabatch.py` assembles.  Fault
 schedules are compiled to capacity-multiplier timelines by `events.py` and
 enter the scan compressed to their piecewise-constant segment snapshots
 (per-slot segment-id gathers re-expand them); ECMP spine assignments
@@ -18,16 +19,13 @@ arrive as step-function segments precomputed by
 `events.ecmp_assign_segments` (the dead-path re-hash depends only on the
 static timeline, so its RNG stream is replayed exactly on the host).
 
-Routing and NIC control exist in two dispatch forms sharing one set of
-branch functions:
-
-  * **static** — `cfg.routing`/`cfg.nic` are concrete strings and the
-    branch is chosen at trace time (the historical per-group path: one
-    compiled program per (scenario, routing, nic) structure);
-  * **traced** — `cfg.routing == cfg.nic == "*"` and a per-batch-element
-    `StackIdx` selects the branch via `lax.switch` inside the traced
-    program, so a whole routing × nic × fault × seed grid runs as ONE
-    compiled program (`megabatch.py` builds those batches).
+Routing and NIC control are chosen per batch element: a `StackIdx`
+selects the branch via `lax.switch` inside the traced program
+(`cfg.routing == cfg.nic == "*"`), so a whole routing × nic × fault ×
+seed grid runs as ONE compiled program.  `megabatch.py` sorts elements
+into lanes by routing, so within a lane the route index is a concrete
+constant and only that routing branch is traced; the NIC index stays
+traced per element.
 
 The per-slot hot paths dispatch through the `repro.kernels` package —
 NIC plane split (`plb_select.plane_split`), quantized-JSQ spine scoring
@@ -62,7 +60,7 @@ from __future__ import annotations
 
 import os
 import threading
-from dataclasses import dataclass, replace
+from dataclasses import dataclass
 from functools import partial
 from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
@@ -90,9 +88,8 @@ from repro.netsim.flight import (collect_dispatch,  # noqa: F401
 from repro.netsim.sim import SimConfig
 from repro.trace import TraceSpec
 
-from .events import (FaultTimeline, compile_fault_timeline,
-                     ecmp_assign_segments, lagged_timeline)
-from .state import FlowBatch, NicCarry, SimCarry, init_carry
+from .events import FaultTimeline, ecmp_assign_segments
+from .state import FlowBatch, NicCarry, SimCarry
 
 _EPS = 1e-12
 
@@ -188,9 +185,10 @@ def flow_chunk_default(n_flows: int, n_planes: int,
 class JxConfig:
     """Static (hashable) simulation parameters: everything `lax.scan`
     needs resolved at trace time — sim knobs, topology shape, and the
-    `FluidFabric` constants.  `routing`/`nic` of `"*"` mean "traced":
-    the slot step expects a per-element `StackIdx` and selects the
-    branch with `lax.switch` (megabatch mode)."""
+    `FluidFabric` constants.  A program's `routing`/`nic` are `"*"`:
+    the slot step takes a per-element `StackIdx` and selects the
+    branch with `lax.switch`; host prep reads a point's own routing
+    from `routing`."""
     slots: int
     slot_us: float
     routing: str
@@ -348,7 +346,7 @@ class JxSimResult:
 
 
 # ---------------------------------------------------------------------------
-# traced branch selection (megabatch mode)
+# traced branch selection
 # ---------------------------------------------------------------------------
 
 ROUTE_PAIR, ROUTE_ECMP = 0, 1
@@ -359,8 +357,8 @@ _BRANCH_IDX = {m: i for i, m in enumerate(_BRANCH_ORDER)}
 
 
 class StackIdx(NamedTuple):
-    """Per-batch-element (routing, nic) branch selectors for the traced
-    dispatch form — scalars under `vmap`, arrays `(B,)` host-side.  The
+    """Per-batch-element (routing, nic) branch selectors — scalars
+    under `vmap`, arrays `(B,)` host-side.  The
     one `nic` index selects both the plane-split and the control-update
     branch (their branch lists share `_BRANCH_ORDER`)."""
     route: jnp.ndarray    # 0 = pair (ar/war), 1 = ecmp
@@ -388,7 +386,7 @@ watch_compiles()
 
 def _device_fingerprint() -> Tuple:
     """Identity of the visible device set — part of every jit-cache and
-    program key, so a `pmap` built for N host devices is never reused
+    program key, so a program sharded over N devices is never reused
     after the device set changes."""
     return tuple((d.platform, d.id) for d in jax.devices())
 
@@ -414,9 +412,7 @@ def _split_mode(cfg: JxConfig, mode: str, nic: NicCarry,
 
 
 def _plane_split(cfg: JxConfig, nic: NicCarry, demand: jnp.ndarray,
-                 stack: Optional[StackIdx] = None) -> jnp.ndarray:
-    if stack is None:
-        return _split_mode(cfg, _SPLIT_MODE[cfg.nic], nic, demand)
+                 stack: StackIdx) -> jnp.ndarray:
     return jax.lax.switch(
         stack.nic,
         [partial(_split_mode, cfg, m, nic, demand)
@@ -502,22 +498,12 @@ def _upd_swlb(cfg, nic, qmean, probe_ok, slot, esr):
 
 
 def _nic_update(cfg: JxConfig, nic: NicCarry, qmean: jnp.ndarray,
-                probe_ok: jnp.ndarray, slot: jnp.ndarray,
-                stack: Optional[StackIdx] = None
+                probe_ok: jnp.ndarray, slot: jnp.ndarray, stack: StackIdx
                 ) -> Tuple[NicCarry, jnp.ndarray, jnp.ndarray]:
     """NIC control update (pre-stall rates, as in `run_sim`), fused with
     the rtt/ecn derivation from the per-flow mean queue.  Returns the
     new carry plus rtt/ecn (for the queue-delay estimate and trace)."""
     F = qmean.shape[0]
-    if stack is None:
-        esr = jnp.full((F, 1), cfg.nic == "esr")
-        if cfg.nic == "dcqcn":
-            return _upd_dcqcn(cfg, nic, qmean, probe_ok, slot, esr)
-        if cfg.nic in ("global", "esr"):
-            return _upd_agg(cfg, nic, qmean, probe_ok, slot, esr)
-        if cfg.nic == "swlb":
-            return _upd_swlb(cfg, nic, qmean, probe_ok, slot, esr)
-        return _upd_spx(cfg, nic, qmean, probe_ok, slot, esr)
     esr = jnp.broadcast_to(jnp.reshape(stack.is_esr, (1, 1)), (F, 1))
     return jax.lax.switch(stack.nic, [
         partial(_upd_spx, cfg, nic, qmean, probe_ok, slot, esr),
@@ -569,11 +555,13 @@ class _AggPerms(NamedTuple):
     run — ECMP's spine assignment is piecewise-constant, so it gets one
     plan per capacity segment.
 
-    The ECMP plan (`ecmp_load`) stacks uplink and downlink buckets into
-    one `(n_seg, P, _plan_rows(cfg), C)` matrix — stage-A up/down
+    The ECMP plan (`_ecmp_load_plan`) stacks uplink and downlink buckets
+    into one `(n_seg, P, _plan_rows(cfg), C)` matrix — stage-A up/down
     buckets, plus the two stage-B (pod–core) bucket families on
-    fat_tree.  In float64 (parity mode) its
-    width axis is summed strictly left-to-right (flow order): those sums
+    fat_tree.  Megabatch ships it as a row of a batch-deduplicated
+    table, so `ecmp_load` here is an inert placeholder.  In float64
+    (parity mode) its width axis is summed strictly left-to-right (flow
+    order): those sums
     feed the queue integrators, where a last-ulp tree-reduction
     difference vs NumPy's sequential `np.add.at` can walk a queue across
     an ECN threshold and fork the trajectory.  Float32 runs take the
@@ -583,7 +571,7 @@ class _AggPerms(NamedTuple):
     src: jnp.ndarray        # (H, Cs)  flows by src host
     dst: jnp.ndarray        # (H, Cd)  flows by dst host
     pair: jnp.ndarray       # (L*L, Cp) flows by (src_leaf, dst_leaf)
-    ecmp_load: jnp.ndarray  # (n_seg, P, L*S + S*L, Cu)
+    ecmp_load: jnp.ndarray  # placeholder (the plan table takes its place)
 
 
 def _perm_matrix(keys: np.ndarray, n_buckets: int, width: int,
@@ -844,11 +832,10 @@ def _route_ecmp(cfg: JxConfig, carry: SimCarry, fabric_rate: jnp.ndarray,
     """ECMP: one-hot spine choice from the precomputed assignment
     segment, loads via padded bucket sums.  `load_fn(seg)` yields the
     (P, LS+SL, C) permutation plan for the current capacity segment —
-    a slice of this element's `_AggPerms.ecmp_load` on the per-group
-    path, a row of the batch-deduplicated plan table on the megabatch
-    path.  `onehots` (`_leaf_onehots`, or None for point gathers)
-    select how each flow reads its path's links (`_link_values`) and
-    how the sparse loads add up (`_ecmp_link_loads`)."""
+    this element's row of the batch-deduplicated plan table.
+    `onehots` (`_leaf_onehots`, or None for point gathers) select how
+    each flow reads its path's links (`_link_values`) and how the
+    sparse loads add up (`_ecmp_link_loads`)."""
     P, L, S = cfg.n_planes, cfg.n_leaves, cfg.n_spines
     assign = assign_segments[seg]                         # (F, P)
     if cfg.agg_mode == "sparse":
@@ -1058,8 +1045,7 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
                seg_down2: jnp.ndarray, seg_dem: jnp.ndarray,
                seg_vup: jnp.ndarray, seg_vdown: jnp.ndarray,
                seg_vup2: jnp.ndarray, seg_vdown2: jnp.ndarray,
-               stack: Optional[StackIdx],
-               load_fn: Callable, carry: SimCarry, xs):
+               stack: StackIdx, load_fn: Callable, carry: SimCarry, xs):
     # timelines are piecewise-constant, so the scan carries only the
     # (n_seg, ...) boundary snapshots and gathers the current segment
     with jax.named_scope("slot/segment"):
@@ -1098,11 +1084,11 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
     # lookups (point gathers, or one-hot contractions on a TPU) + padded
     # bucket sums.  The topology kind is static, so the branch
     # list holds that kind's pair/ecmp implementations (fat-tree ones
-    # also return stage-B loads); under traced dispatch `lax.switch`
+    # also return stage-B loads); `lax.switch` on a traced route index
     # evaluates both branches for the whole batch and selects per
     # element.
     with jax.named_scope("slot/route"):
-        use_war = cfg.routing == "war" if stack is None else stack.is_war
+        use_war = stack.is_war
         if cfg.kind == "fat_tree":
             branches = [
                 partial(_route_pair_ft, cfg, carry, fabric_rate, up, down,
@@ -1116,9 +1102,7 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
                         upv, downv, aggs, pair_idx, use_war),
                 partial(_route_ecmp, cfg, carry, fabric_rate, up, down,
                         fb, assign_segments, load_fn, seg, onehots)]
-        if stack is None:
-            routed = branches[1 if cfg.routing == "ecmp" else 0]()
-        elif isinstance(stack.route, int):
+        if isinstance(stack.route, int):
             # lane-sorted megabatch: the dispatcher grouped elements by
             # route, so the per-element index is concrete within the
             # lane and only that branch is traced (no switch tax)
@@ -1235,28 +1219,27 @@ def _slot_step(cfg: JxConfig, fb: FlowBatch, pair_idx: jnp.ndarray,
                        tuple(sig[f]() for f in cfg.trace.active_fields()))
 
 
-def _simulate(cfg: JxConfig, fb: FlowBatch, seg_up, seg_down, seg_acc,
-              seg_up2, seg_down2, seg_dem, seg_vup, seg_vdown, seg_vup2,
-              seg_vdown2, assign_segments, aggs, seg_id,
-              stack=None, carry0=None, ecmp_table=None, uid=None):
+def _simulate(cfg: JxConfig, stack: StackIdx, carry0: SimCarry,
+              fb: FlowBatch, seg_up, seg_down, seg_acc, seg_up2,
+              seg_down2, seg_dem, seg_vup, seg_vdown, seg_vup2,
+              seg_vdown2, assign_segments, aggs, uid, seg_id, ecmp_table):
+    """One batch element: traced branch dispatch + donated carry.  Every
+    argument between `stack` and `seg_id` (inclusive) is vmapped;
+    `ecmp_table` is batch-constant (the deduplicated ECMP plan table,
+    whose row `uid` is this element's)."""
     if cfg.flow_chunk:
         # streaming path: the flow axis runs through the slot step in
         # fixed-size chunks (sparse aggregation only — `aggs`/the ECMP
         # plan table are never gathered there)
         from . import chunked
         return chunked.simulate_chunked(
-            cfg, fb, seg_up, seg_down, seg_acc, seg_up2, seg_down2,
-            seg_dem, seg_vup, seg_vdown, seg_vup2, seg_vdown2,
-            assign_segments, seg_id, stack=stack, carry0=carry0)
-    if carry0 is None:
-        carry0 = init_carry(fb, cfg)
-    if ecmp_table is None:
-        def load_fn(seg):
-            return aggs.ecmp_load[seg]
-    else:
-        # batch-deduplicated plan table: `uid` picks this element's row
-        def load_fn(seg):
-            return ecmp_table[uid, seg]
+            cfg, stack, carry0, fb, seg_up, seg_down, seg_acc, seg_up2,
+            seg_down2, seg_dem, seg_vup, seg_vdown, seg_vup2, seg_vdown2,
+            assign_segments, seg_id)
+
+    def load_fn(seg):
+        return ecmp_table[uid, seg]
+
     pair_idx = fb.src_leaf * cfg.n_leaves + fb.dst_leaf
     onehots = (_leaf_onehots(cfg, fb)
                if ecmp_onehot(cfg, fb.src.shape[0]) else None)
@@ -1290,48 +1273,6 @@ def _simulate(cfg: JxConfig, fb: FlowBatch, seg_up, seg_down, seg_acc,
             carry.util_up) + bh + tail
 
 
-def _simulate_mb(cfg: JxConfig, stack: StackIdx, carry0: SimCarry,
-                 fb: FlowBatch, seg_up, seg_down, seg_acc, seg_up2,
-                 seg_down2, seg_dem, seg_vup, seg_vdown, seg_vup2,
-                 seg_vdown2, assign_segments, aggs, uid, seg_id,
-                 ecmp_table):
-    """Megabatch element: traced branch dispatch + donated carry.  Every
-    argument between `stack` and `seg_id` (inclusive) is vmapped;
-    `ecmp_table` is batch-constant (the deduplicated ECMP plan table)."""
-    return _simulate(cfg, fb, seg_up, seg_down, seg_acc, seg_up2,
-                     seg_down2, seg_dem, seg_vup, seg_vdown, seg_vup2,
-                     seg_vdown2, assign_segments, aggs, seg_id,
-                     stack=stack, carry0=carry0, ecmp_table=ecmp_table,
-                     uid=uid)
-
-
-def _jitted(cfg: JxConfig, batched: bool, n_shards: int = 1):
-    """Compiled per-group entry point, memoized on (cfg, batch form,
-    shard count, *and the visible device set*) — a `pmap` callable built
-    for N devices must not be silently reused if the device set changes
-    mid-process (regression-tested)."""
-    key = ("group", cfg, batched, n_shards, _device_fingerprint())
-    fn = _JIT_CACHE.get(key)
-    if fn is not None:
-        return fn
-    fn = partial(_simulate, cfg)
-    if not batched:
-        fn = jax.jit(fn)
-    else:
-        fn = jax.vmap(fn, in_axes=(0,) * 13 + (None,))
-        if n_shards == 1:
-            fn = jax.jit(fn)
-        else:
-            # shard the batch axis over host devices: XLA CPU serializes
-            # separate executions even across devices, but one pmap
-            # launch runs its per-device shards on parallel threads —
-            # the single-process equivalent of the NumPy backend's
-            # process pool
-            fn = jax.pmap(fn, in_axes=(0,) * 13 + (None,))
-    _JIT_CACHE[key] = fn
-    return fn
-
-
 def lane_mesh(n_shards: int) -> "jax.sharding.Mesh":
     """1-D device mesh over the megabatch lane (batch) axis.  Today the
     axis spans local host devices; under `jax.distributed` the same
@@ -1350,8 +1291,7 @@ def _jitted_mb(cfg: JxConfig, n_shards: int = 1,
     instead of allocating a second batch.  With `n_shards > 1` the
     batch axis is split over a 1-D "lane" device mesh (`lane_mesh`):
     operands arrive flat `(B, ...)` and `shard_map` hands each device
-    its own contiguous block — the replacement for the old
-    device-major `pmap` layout, structured to extend to
+    its own contiguous block, structured to extend to
     `jax.distributed` meshes.
 
     `lanes` is the dispatcher's static per-shard layout: a tuple of
@@ -1365,11 +1305,11 @@ def _jitted_mb(cfg: JxConfig, n_shards: int = 1,
     if fn is not None:
         return fn
     if lanes is None:
-        body = jax.vmap(partial(_simulate_mb, cfg),
+        body = jax.vmap(partial(_simulate, cfg),
                         in_axes=(0,) * 17 + (None,))
     else:
         stack_axes = StackIdx(route=None, is_war=0, nic=0, is_esr=0)
-        v = jax.vmap(partial(_simulate_mb, cfg),
+        v = jax.vmap(partial(_simulate, cfg),
                      in_axes=(stack_axes,) + (0,) * 16 + (None,))
         tm = jax.tree_util.tree_map
 
@@ -1462,38 +1402,6 @@ def _warn_f32_bytes(name: str, fa: FlowArrays, stacklevel: int = 3
         # the condition in `f32_overflow_log` above.
         import warnings
         warnings.warn(msg, stacklevel=stacklevel)
-
-
-def _prepared(compiled
-              ) -> Tuple[JxConfig, FlowArrays, FaultTimeline,
-                         Optional[np.ndarray],
-                         Optional[FaultTimeline]]:
-    """Returns `(cfg, flow arrays, physical timeline, phase mult,
-    visible timeline)` — the visible timeline is the reaction-lagged
-    view (None when reaction is off, or the physical timeline itself
-    when the reaction's total lag is zero)."""
-    from repro.scenarios.spec import reaction_lag
-    spec = compiled.spec
-    cfg = JxConfig.from_sim(compiled.cfg, spec.topo)
-    fa = FlowArrays.build(compiled.flows, compiled.topo)
-    _warn_f32_bytes(spec.name, fa, stacklevel=4)
-    pm = getattr(compiled, "phase_mult", None)
-    if pm is not None:
-        cfg = replace(cfg, n_phases=int(pm.shape[1]))
-    tl = compile_fault_timeline(spec)
-    vtl = None
-    r = spec.reaction
-    if r is not None and r.enabled:
-        cfg = replace(cfg, react=True)
-        lag = reaction_lag(r, spec.sim.routing)
-        vtl = lagged_timeline(tl, lag) if lag > 0 else tl
-    chunk = flow_chunk_default(len(fa), cfg.n_planes, cfg.agg_mode)
-    if chunk and not cfg.trace.enabled:
-        # chunked streaming implies sparse aggregation (a forced
-        # REPRO_JX_FLOW_CHUNK coerces it; the auto heuristic only fires
-        # on already-sparse shapes)
-        cfg = replace(cfg, agg_mode="sparse", flow_chunk=chunk)
-    return cfg, fa, tl, pm, vtl
 
 
 def phase_boundaries(pm: Optional[np.ndarray]) -> List[int]:
@@ -1652,8 +1560,8 @@ def _agg_widths(cfg: JxConfig, fa: FlowArrays,
 def _ecmp_load_plan(cfg: JxConfig, fa: FlowArrays, assign: np.ndarray,
                     wu: int, pad: int) -> np.ndarray:
     """(n_seg, P, `_plan_rows(cfg)`, wu) ECMP load-aggregation plan (see
-    `_AggPerms.ecmp_load`) — the single builder shared by the per-group
-    and megabatch paths, so their 1e-5 row-identity cannot drift."""
+    `_AggPerms.ecmp_load`): one row of megabatch's deduplicated plan
+    table."""
     P, L, S = cfg.n_planes, cfg.n_leaves, cfg.n_spines
 
     def plane(g, p):
@@ -1673,31 +1581,23 @@ def _ecmp_load_plan(cfg: JxConfig, fa: FlowArrays, assign: np.ndarray,
         for g in range(assign.shape[0])])
 
 
-def _aggs_for(cfg: JxConfig, fa: FlowArrays, assign: np.ndarray,
-              widths: Tuple[int, ...],
-              pad: Optional[int] = None) -> _AggPerms:
-    """`pad` is the index that reads the appended zero row in
-    `_seg_sum` — the row count of the (possibly flow-padded) batch, not
+def _flow_perms(cfg: JxConfig, fa: FlowArrays, widths: Tuple[int, ...],
+                pad: int) -> Tuple[np.ndarray, ...]:
+    """One point's src-host, dst-host and leaf-pair gather plans (the
+    first three `_AggPerms`).  `pad` is the index that reads the
+    appended zero row in `_seg_sum`: the flow bucket of the batch, not
     necessarily `len(fa)`."""
-    ws, wd, wp, wu = widths
-    H, L, P = cfg.n_hosts, cfg.n_leaves, cfg.n_planes
-    F = len(fa) if pad is None else pad
     if cfg.agg_mode == "sparse":
         # sparse mode aggregates by (plane, link) keys computed from the
         # flow batch inside the traced program; the gather plans are
         # never indexed, so ship inert minimal placeholders
         z = np.zeros((1, 1), np.int32)
-        return _AggPerms(src=z, dst=z, pair=z,
-                         ecmp_load=np.zeros((1, P, 1, 1), np.int32))
-    if cfg.routing == "ecmp":
-        load = _ecmp_load_plan(cfg, fa, assign, wu, F)
-    else:
-        load = np.full((1, P, 1, 1), F, np.int32)
-    return _AggPerms(
-        src=_perm_matrix(fa.src, H, ws, F),
-        dst=_perm_matrix(fa.dst, H, wd, F),
-        pair=_perm_matrix(fa.src_leaf * L + fa.dst_leaf, L * L, wp, F),
-        ecmp_load=load)
+        return z, z, z
+    ws, wd, wp = widths[:3]
+    H, L = cfg.n_hosts, cfg.n_leaves
+    return (_perm_matrix(fa.src, H, ws, pad),
+            _perm_matrix(fa.dst, H, wd, pad),
+            _perm_matrix(fa.src_leaf * L + fa.dst_leaf, L * L, wp, pad))
 
 
 def _wrap(cfg: JxConfig, fa: FlowArrays, out) -> JxSimResult:
@@ -1722,122 +1622,7 @@ def _wrap(cfg: JxConfig, fa: FlowArrays, out) -> JxSimResult:
 
 
 def run_compiled(compiled) -> JxSimResult:
-    """Simulate one `CompiledScenario` on the JAX backend."""
-    global _BACKEND_USED
-    _BACKEND_USED = True
-    cfg, fa, tl, pm, vtl = _prepared(compiled)
-    boundaries = set(tl.change_slots()) | set(phase_boundaries(pm))
-    if vtl is not None:
-        boundaries |= set(vtl.change_slots())
-    boundaries = tuple(sorted(boundaries))
-    r = compiled.spec.reaction
-    segs = _assign_for(cfg, fa, tl, compiled.cfg.seed, boundaries,
-                       vtl=vtl, mode=r.mode if cfg.react else "instant",
-                       backup=getattr(compiled, "backup", None))
-    aggs = _aggs_for(cfg, fa, segs, _agg_widths(cfg, fa, segs))
-    up, down, acc, up2, down2 = _seg_caps(tl, boundaries)
-    vup, vdown, vup2, vdown2 = _vis_seg_caps(
-        vtl if cfg.react else None, boundaries, cfg.n_planes)
-    args = (FlowBatch.from_arrays(fa), up, down, acc, up2, down2,
-            _seg_dem(pm, boundaries), vup, vdown, vup2, vdown2, segs,
-            aggs, _seg_id(boundaries, cfg.slots))
-    _record_launch("group", (cfg, False, 1), args)
-    out = _jitted(cfg, False)(*args)
-    return _wrap(cfg, fa, out)
-
-
-def dispatch_compiled_batch(points: List):
-    """Build and asynchronously dispatch one batch of structurally
-    identical `CompiledScenario`s (same scenario / routing / nic /
-    slots — only seeds differ).  Returns an opaque handle for
-    `finalize_batch`; the computation runs concurrently with whatever
-    the caller does next (JAX CPU execution is async).  With
-    `XLA_FLAGS=--xla_force_host_platform_device_count=N` the batch axis
-    is `pmap`-sharded over the N host devices (padding the batch by
-    replicating the last point if needed), keeping every core busy
-    without a process pool."""
-    global _BACKEND_USED
-    _BACKEND_USED = True
-    prepared = [_prepared(c) for c in points]
-    cfg = prepared[0][0]
-    F = len(prepared[0][1])
-    for c, (cfg_i, fa_i, _, _, _) in zip(points, prepared):
-        if cfg_i != cfg or len(fa_i) != F:
-            raise ValueError(
-                "batched points must be structurally identical "
-                f"(got {cfg_i} with {len(fa_i)} flows vs {cfg} with {F}); "
-                "group grid points by (scenario, routing, nic) first")
-    # shared segment boundaries: union of capacity-change AND
-    # phase-change slots (and visible-capacity changes under reaction),
-    # so every element's ECMP re-hash replay sees each capacity change
-    # exactly once and the demand timeline is piecewise-constant per
-    # segment
-    boundaries = tuple(sorted(
-        {b for _, _, tl, _, _ in prepared for b in tl.change_slots()}
-        | {b for _, _, _, pm, _ in prepared
-           for b in phase_boundaries(pm)}
-        | {b for _, _, _, _, vtl in prepared if vtl is not None
-           for b in vtl.change_slots()}))
-    assigns = [
-        _assign_for(
-            cfg, fa, tl, c.cfg.seed, boundaries, vtl=vtl,
-            mode=(c.spec.reaction.mode if cfg.react else "instant"),
-            backup=getattr(c, "backup", None))
-        for c, (_, fa, tl, _, vtl) in zip(points, prepared)]
-    widths = tuple(map(max, zip(*(
-        _agg_widths(cfg, fa, a)
-        for (_, fa, _, _, _), a in zip(prepared, assigns)))))
-    aggs = [_aggs_for(cfg, fa, a, widths)
-            for (_, fa, _, _, _), a in zip(prepared, assigns)]
-    fb = FlowBatch.stack([fa for _, fa, _, _, _ in prepared])
-    caps = [_seg_caps(tl, boundaries) for _, _, tl, _, _ in prepared]
-    up, down, acc, up2, down2 = (np.stack(col) for col in zip(*caps))
-    vcaps = [_vis_seg_caps(vtl if cfg.react else None, boundaries,
-                           cfg.n_planes)
-             for _, _, _, _, vtl in prepared]
-    vup, vdown, vup2, vdown2 = (np.stack(col) for col in zip(*vcaps))
-    dem = np.stack([_seg_dem(pm, boundaries)
-                    for _, _, _, pm, _ in prepared])
-    seg_id = _seg_id(boundaries, cfg.slots)
-    aggs_b = _AggPerms(*(np.stack(col) for col in zip(*aggs)))
-    args = [fb, up, down, acc, up2, down2, dem, vup, vdown, vup2,
-            vdown2, np.stack(assigns), aggs_b]
-    B = len(points)
-    n_dev = len(jax.devices())
-    shards = min(B, n_dev) if n_dev > 1 and B > 1 else 1
-    if shards > 1:
-        padded = -B % shards
-
-        def shape(a):
-            if padded:
-                a = np.concatenate(
-                    [np.asarray(a),
-                     np.repeat(np.asarray(a)[-1:], padded, 0)])
-            return np.asarray(a).reshape(
-                (shards, (B + padded) // shards) + np.shape(a)[1:])
-
-        args = [jax.tree_util.tree_map(shape, a) for a in args]
-    _record_launch("group", (cfg, True, shards), args)
-    out = _jitted(cfg, True, shards)(*args, seg_id)
-    # keep only what finalize needs — dropping the dense per-point
-    # timelines here frees O(B*T*fabric) host memory while the batch
-    # computes
-    return cfg, [fa for _, fa, _, _, _ in prepared], shards, out
-
-
-def finalize_batch(handle) -> List[JxSimResult]:
-    """Block on a `dispatch_compiled_batch` handle and unpack per-point
-    results (dropping any pmap padding)."""
-    cfg, fas, shards, out = handle
-    outs = [np.asarray(o) for o in out]
-    if shards > 1:
-        outs = [o.reshape((-1,) + o.shape[2:]) for o in outs]
-    return [_wrap(cfg, fa, [o[b] for o in outs])
-            for b, fa in enumerate(fas)]
-
-
-def run_compiled_batch(points: List) -> List[JxSimResult]:
-    """Simulate a batch of `CompiledScenario`s that share structure as
-    one batched (vmap, pmap-sharded when multiple host devices exist)
-    computation — the JAX replacement for the process-pool sweep."""
-    return finalize_batch(dispatch_compiled_batch(points))
+    """Simulate one `CompiledScenario` on the JAX backend: a one-lane
+    megabatch."""
+    from . import megabatch
+    return megabatch.run_megabatch([compiled])[0]

@@ -1,16 +1,14 @@
 """Megabatch dispatch: one XLA launch per experiment sweep.
 
-The per-group path (`engine.dispatch_compiled_batch`) batches only the
-seed axis: every distinct (scenario, routing, nic, fault) structure is
-its own compiled program and its own launch, so a routing × nic × fault
-grid pays tens of compiles and serialized dispatches.  This module
-instead stacks *every* point of a grid into one `jit(vmap)` launch —
-sharded over the lane axis with a `jax.sharding` Mesh/NamedSharding
-when multiple devices are visible:
+The JAX backend's only dispatch path: it stacks *every* point of a grid
+into one `jit(vmap)` launch — sharded over the lane axis with a
+`jax.sharding` Mesh when multiple devices are visible — so a routing ×
+nic × fault × seed grid pays one compile and one launch per structure,
+not one per point (`engine.run_compiled` is a one-point megabatch):
 
   * `routing` / `nic` become per-element `StackIdx` branch selectors,
-    resolved by `lax.switch` inside the traced program (the engine's
-    "traced" dispatch form, `JxConfig.routing == nic == "*"`);
+    resolved by `lax.switch` inside the traced program
+    (`JxConfig.routing == nic == "*"`);
   * flow counts and fault-timeline segment counts are padded up to
     power-of-two buckets so heterogeneous points share static shapes —
     pad flows are inert (zero demand, infinite bytes, never started)
@@ -30,15 +28,14 @@ when multiple devices are visible:
 Points that cannot share a program (different topology shape, slot
 count, record cadence, … or a different shape bucket) split into
 multiple launches — still one per *structure*, never one per point.
-Row-identity with the per-group path (1e-5, x64) is pinned by
-`tests/test_megabatch.py`.
+Row-identity of a fused grid with each point run alone (1e-5, x64) is
+pinned by `tests/test_megabatch.py`.
 
 Multi-device runs hand the batch to `engine._jitted_mb` as flat
-`(B, ...)` arrays with lane-axis `NamedSharding`s; the jitted program
-reshapes to `(shards, B//shards, ...)` internally so each mesh device
-sees the same static per-shard lane layout the old `pmap` path used.
-The mesh is 1-D over `jax.devices()`, so the same code path extends to
-multi-process `jax.distributed` meshes later.
+`(B, ...)` arrays, dealt device-major; `shard_map` gives each mesh device
+its contiguous block, so every device sees the same static per-shard
+lane layout.  The mesh is 1-D over `jax.devices()`, so the same code
+path extends to multi-process `jax.distributed` meshes later.
 
 `plan_megabatch` / `dispatch_planned` split the grouping (cheap,
 structural) from the host prep + launch (expensive, memoized) so
@@ -234,8 +231,7 @@ def _padded_flow_cols(fa: FlowArrays, F_b: int, slots: int
 def _ecmp_plan(cfg: JxConfig, fa: FlowArrays, assign: np.ndarray,
                wu: int, F_b: int, seg_b: int) -> np.ndarray:
     """(seg_b, P, L*S + S*L, wu) ECMP load-aggregation plan — one table
-    row, flow-padded to `F_b` (built by the same
-    `engine._ecmp_load_plan` the per-group path uses) and
+    row, flow-padded to `F_b` (`engine._ecmp_load_plan`) and
     segment-padded to the bucket."""
     return _pad_segs(engine._ecmp_load_plan(cfg, fa, assign, wu, F_b),
                      seg_b)
@@ -243,8 +239,8 @@ def _ecmp_plan(cfg: JxConfig, fa: FlowArrays, assign: np.ndarray,
 
 def _carry0(B: int, F_b: int, cfg: JxConfig,
             remaining: np.ndarray) -> engine.SimCarry:
-    """Batched initial scan carry (the donated argument), mirroring
-    `state.init_carry`'s dtypes under the active x64 setting."""
+    """Batched initial scan carry (the donated argument), in the active
+    x64 setting's dtypes."""
     from .state import NicCarry, SimCarry, probe_miss_dtype, stage_shapes
     x64 = bool(jax.config.jax_enable_x64)
     fdt = np.float64 if x64 else np.float32
@@ -318,9 +314,8 @@ def _assemble_group(cfg: JxConfig, pts: List[_Point], caches: Dict,
         pkey = ("perms", p.fa_key, widths[:3], F_b)
         perms = caches.get(pkey)
         if perms is None:
-            a = engine._aggs_for(replace(cfg, routing="ar"), p.fa,
-                                 zero_assign, widths, pad=F_b)
-            perms = caches[pkey] = (a.src, a.dst, a.pair)
+            perms = caches[pkey] = engine._flow_perms(cfg, p.fa, widths,
+                                                      F_b)
         uid = 0
         assign = zero_assign
         if p.routing == "ecmp":

@@ -9,12 +9,10 @@ post-warmup goodput accumulator that replaces the NumPy backend's dense
 """
 from __future__ import annotations
 
-from typing import List, NamedTuple, Tuple
+from typing import NamedTuple, Tuple
 
 import jax.numpy as jnp
 import numpy as np
-
-from repro.netsim.fabric import FlowArrays
 
 
 class FlowBatch(NamedTuple):
@@ -27,36 +25,6 @@ class FlowBatch(NamedTuple):
     start_slot: jnp.ndarray    # (F,) int
     same_leaf: jnp.ndarray     # (F,) bool
     phase: jnp.ndarray         # (F,) int demand-timeline lane
-
-    @classmethod
-    def from_arrays(cls, fa: FlowArrays) -> "FlowBatch":
-        return cls(
-            src=jnp.asarray(fa.src), dst=jnp.asarray(fa.dst),
-            src_leaf=jnp.asarray(fa.src_leaf),
-            dst_leaf=jnp.asarray(fa.dst_leaf),
-            demand=jnp.asarray(fa.demand),
-            bytes_total=jnp.asarray(fa.bytes_total),
-            start_slot=jnp.asarray(fa.start_slot),
-            same_leaf=jnp.asarray(fa.src_leaf == fa.dst_leaf),
-            phase=jnp.asarray(fa.phase))
-
-    @classmethod
-    def stack(cls, fas: List[FlowArrays]) -> "FlowBatch":
-        """(B, F) batch for `vmap` — flow counts must match (they do for
-        grid points of one scenario: only seeds differ, not structure)."""
-        cols = {
-            "src": [fa.src for fa in fas],
-            "dst": [fa.dst for fa in fas],
-            "src_leaf": [fa.src_leaf for fa in fas],
-            "dst_leaf": [fa.dst_leaf for fa in fas],
-            "demand": [fa.demand for fa in fas],
-            "bytes_total": [fa.bytes_total for fa in fas],
-            "start_slot": [fa.start_slot for fa in fas],
-            "same_leaf": [fa.src_leaf == fa.dst_leaf for fa in fas],
-            "phase": [fa.phase for fa in fas],
-        }
-        return cls(**{k: jnp.asarray(np.stack(v))
-                      for k, v in cols.items()})
 
 
 class NicCarry(NamedTuple):
@@ -86,8 +54,8 @@ class SimCarry(NamedTuple):
 
 def stage_shapes(cfg) -> Tuple[Tuple[int, int, int], Tuple[int, int, int]]:
     """((P, L, n_up), (P, pods_b, cores_b)) queue/capacity shapes for a
-    `JxConfig`-like object — the single source of truth both backends'
-    carry builders use."""
+    `JxConfig`-like object — the single source of truth of the carry's
+    queue shapes."""
     P, L = cfg.n_planes, cfg.n_leaves
     if cfg.kind == "fat_tree":
         return (P, L, cfg.n_aggs), (P, cfg.n_pods, cfg.n_cores)
@@ -97,33 +65,9 @@ def stage_shapes(cfg) -> Tuple[Tuple[int, int, int], Tuple[int, int, int]]:
 def probe_miss_dtype(cfg, float_dtype) -> jnp.dtype:
     """int8 under the compact carry (float32 runs only — the probe
     counter saturates at `probe_timeout`, far inside int8 range); the
-    default integer width otherwise.  Shared by `init_carry` and the
-    megabatch host-side carry builder."""
+    default integer width otherwise.  Read by the megabatch host-side
+    carry builder."""
     if (getattr(cfg, "compact_carry", False)
             and jnp.dtype(float_dtype) == jnp.float32):
         return jnp.dtype(jnp.int8)
     return jnp.asarray(np.int64(0)).dtype
-
-
-def init_carry(fb: FlowBatch, cfg) -> SimCarry:
-    F = fb.src.shape[0]
-    (P, L, U), b_shape = stage_shapes(cfg)
-    dtype = jnp.asarray(0.0).dtype          # float64 iff x64 enabled
-    itype = jnp.asarray(np.int64(0)).dtype
-    nic = NicCarry(
-        rate=jnp.ones((F, P), dtype),
-        alpha=jnp.zeros((F, P), dtype),
-        probe_miss=jnp.zeros((F, P), probe_miss_dtype(cfg, dtype)),
-        eligible=jnp.ones((F, P), bool),
-        pending_fail=jnp.zeros((F, P), itype))
-    return SimCarry(
-        q_up=jnp.zeros((P, L, U), dtype),
-        q_down=jnp.zeros((P, U, L), dtype),
-        q2_up=jnp.zeros(b_shape, dtype),
-        q2_down=jnp.zeros(b_shape, dtype),
-        nic=nic,
-        remaining=fb.bytes_total.astype(dtype),
-        done=jnp.zeros(F, bool),
-        completion=jnp.full(F, -1, itype),
-        goodput_sum=jnp.zeros(F, dtype),
-        util_up=jnp.zeros((P, L, U), dtype))

@@ -550,8 +550,8 @@ def giga_fabric_storage() -> ScenarioSpec:
     Fig 14's giga-scale resiliency sweeps.  At this size the dense
     (leaves x leaves x paths x planes) load matrices are the memory
     bottleneck, so `agg_mode_default` flips the JAX engine to the
-    sparse segment-summed path — `benchmarks/backend_bench.py --large`
-    and `benchmarks/fig14_large_scale.py --giga` both time it."""
+    sparse segment-summed path; `benchmarks/fig14_large_scale.py --giga`
+    times it."""
     return ScenarioSpec(
         name="giga_fabric_storage",
         description="Giga-scale point: 256 leaves x 16 hosts, 2 planes, "

@@ -401,7 +401,7 @@ def sweep(spec_or_name, grid: Optional[SweepGrid] = None,
     """Run one scenario over a (seed × routing × nic) grid.
 
     Deprecated shim: lowers onto `repro.experiments.execute_points` (the
-    `Experiment` API's executor) — same process-pool / grouped-vmap
+    `Experiment` API's executor) — same process-pool / megabatch
     dispatch, same row order.  Prefer `repro.experiments.Experiment`,
     which also sweeps arbitrary spec axes, caches, and resumes."""
     from repro.experiments.execute import execute_points

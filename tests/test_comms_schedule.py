@@ -305,8 +305,7 @@ def test_schedule_megabatch_single_compile():
                      axes=Axis("seed", (0, 1)))
     points = [p.spec for p in exp.points()]
     reset_dispatch_stats()
-    rows = execute_points(points, backend="jax",
-                          jx_dispatch="megabatch")
+    rows = execute_points(points, backend="jax")
     stats = dispatch_stats()
     assert stats["dispatches"] == 1
     assert stats["compiles"] == 1

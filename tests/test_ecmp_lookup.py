@@ -123,16 +123,18 @@ def test_megabatch_launch_with_the_contraction_equals_the_gathers(
         np.testing.assert_array_equal(_bits(c), _bits(g))
 
 
-def test_per_group_run_with_the_contraction_equals_the_gathers(
+def test_single_point_run_with_the_contraction_equals_the_gathers(
         lookups, monkeypatch):
-    """The per-group `_simulate` path under x64, the contraction chosen
-    by the platform rule as a TPU would choose it."""
+    """One point alone (`run_compiled`, a one-lane megabatch) under x64,
+    the contraction chosen by the platform rule as a TPU would choose
+    it."""
     spec = _giga_tiny(react=False)[0]
     with jax.enable_x64(True):
         gathered = engine.run_compiled(compile_scenario(spec))
         assert not lookups
         monkeypatch.setattr(engine, "ecmp_onehot_budget",
                             lambda platform: FORCED)
+        monkeypatch.setattr(engine, "_JIT_CACHE", {})
         contracted = engine.run_compiled(compile_scenario(spec))
         assert lookups
     for f in ("mean_goodput", "completion_slot", "total_goodput",

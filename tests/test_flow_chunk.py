@@ -132,13 +132,11 @@ def test_chunked_megabatch_row_identity_x64():
                      Axis("sim.slots", (80,))))
     points = [p.spec for p in exp.points()]
     with jax.enable_x64(True):
-        mono = execute_points(points, backend="jax",
-                              jx_dispatch="megabatch")
+        mono = execute_points(points, backend="jax")
         prev = os.environ.get("REPRO_JX_FLOW_CHUNK")
         os.environ["REPRO_JX_FLOW_CHUNK"] = "17"
         try:
-            chunked = execute_points(points, backend="jax",
-                                     jx_dispatch="megabatch")
+            chunked = execute_points(points, backend="jax")
         finally:
             if prev is None:
                 del os.environ["REPRO_JX_FLOW_CHUNK"]
@@ -162,8 +160,7 @@ def test_chunked_no_donation_warnings_leak():
     try:
         with warnings.catch_warnings(record=True) as caught:
             warnings.simplefilter("always")
-            execute_points([spec], backend="jax",
-                           jx_dispatch="megabatch")
+            execute_points([spec], backend="jax")
     finally:
         if prev is None:
             del os.environ["REPRO_JX_FLOW_CHUNK"]

@@ -1,18 +1,20 @@
-"""Megabatch dispatch: one fused launch per sweep, row-identical to the
-per-group path.
+"""Megabatch dispatch: one fused launch per sweep, row-identical to
+each point run alone.
 
 The megabatch path lifts routing/nic into per-element traced branch
 selectors and stacks whole grids into one `jit(vmap)` launch
 (`repro.netsim.jx.megabatch`).  These tests pin:
 
-  * row-identity (1e-5, x64) against the per-group executor across the
-    full routing × nic cross, mixed fault timelines (fault axes with
+  * row-identity (1e-5, x64) of the fused grid against each point run
+    alone through `run_compiled` (a one-lane megabatch) across the full
+    routing × nic cross, mixed fault timelines (fault axes with
     differing segment counts), and mixed scenarios whose flow counts
-    land in different padding buckets;
+    land in different padding buckets — so lane packing, bucket
+    padding and the deduplicated ECMP plan table stay inert;
   * the single-launch property: a multi-axis grid = 1 dispatch and 1
-    program compile (the bench JSON's acceptance metric);
-  * the `_jitted` device-fingerprint regression (a pmap built for N
-    devices must not be reused when the device set changes).
+    program compile;
+  * the `_jitted_mb` device-fingerprint regression (a program sharded
+    over N devices must not be reused when the device set changes).
 """
 import jax
 import numpy as np
@@ -21,7 +23,7 @@ import pytest
 from repro.experiments import Axis, Experiment, execute_points, product
 from repro.netsim.jx import dispatch_stats, reset_dispatch_stats
 from repro.netsim.jx import engine
-from repro.scenarios import list_scenarios
+from repro.scenarios import compile_scenario, distill_metrics, list_scenarios
 
 TOL = 1e-5
 
@@ -33,16 +35,19 @@ def _grid_points(base, axes):
 
 
 def _run_both(points):
+    """Metrics of each point run alone (`run_compiled`), then of the
+    fused grid, at x64."""
     with jax.enable_x64(True):
-        group = execute_points(points, backend="jax",
-                               jx_dispatch="group")
-        mega = execute_points(points, backend="jax",
-                              jx_dispatch="megabatch")
-    return group, mega
+        alone = []
+        for p in points:
+            c = compile_scenario(p)
+            alone.append(distill_metrics(p, c, engine.run_compiled(c)))
+        mega = execute_points(points, backend="jax")
+    return alone, mega
 
 
-def _assert_rows_identical(points, group, mega):
-    for p, a, b in zip(points, group, mega):
+def _assert_rows_identical(points, alone, mega):
+    for p, a, b in zip(points, alone, mega):
         where = f"{p.name} {p.sim.routing}/{p.sim.nic} seed={p.sim.seed}"
         assert b.to_row() == a.to_row(), where
         assert b.mean_goodput == pytest.approx(a.mean_goodput, abs=TOL)
@@ -62,16 +67,15 @@ def _assert_rows_identical(points, group, mega):
 
 def test_megabatch_full_routing_nic_cross_row_identity():
     """The acceptance claim: every (routing, nic) pair of the registry
-    cross, fused into one launch, matches the per-group dispatch."""
+    cross, fused into one launch, matches each point run alone."""
     points = _grid_points("flap_during_incast", [
         Axis("sim.routing", ("ar", "war", "ecmp")),
         Axis("sim.nic", ("spx", "dcqcn", "global", "esr", "swlb")),
         Axis("seed", (0, 1)),
         Axis("sim.slots", (100,)),
     ])
-    reset_dispatch_stats()
-    group, mega = _run_both(points)
-    _assert_rows_identical(points, group, mega)
+    alone, mega = _run_both(points)
+    _assert_rows_identical(points, alone, mega)
 
 
 def test_megabatch_mixed_fault_timelines():
@@ -85,8 +89,8 @@ def test_megabatch_mixed_fault_timelines():
         Axis("seed", (0, 1)),
         Axis("sim.slots", (160,)),
     ])
-    group, mega = _run_both(points)
-    _assert_rows_identical(points, group, mega)
+    alone, mega = _run_both(points)
+    _assert_rows_identical(points, alone, mega)
 
 
 def test_megabatch_mixed_scenarios_flow_buckets():
@@ -104,18 +108,17 @@ def test_megabatch_mixed_scenarios_flow_buckets():
         Axis("sim.slots", (120,)),
     ])
     reset_dispatch_stats()
-    group, mega = _run_both(points)
+    alone, mega = _run_both(points)
     stats = dispatch_stats()
-    # megabatch: two flow buckets -> exactly 2 fused launches for 12
-    # points (the per-group path dispatched 6 structures before it)
-    assert stats["dispatches"] - 6 == 2
-    _assert_rows_identical(points, group, mega)
+    # two flow buckets -> exactly 2 fused launches for 12 points (after
+    # the 12 one-point launches of the points run alone)
+    assert stats["dispatches"] - len(points) == 2
+    _assert_rows_identical(points, alone, mega)
 
 
 def test_megabatch_multi_axis_grid_single_compile():
     """A 3-axis grid (nic x fault x seed) is ONE dispatch and ONE
-    program compile — the dispatch-count metric CI asserts from
-    BENCH_backend.json.  slots=101 keeps the program fingerprint unique
+    program compile.  slots=101 keeps the program fingerprint unique
     to this test regardless of suite order."""
     points = _grid_points("flap_during_incast", [
         Axis("sim.routing", ("ar", "war", "ecmp")),
@@ -125,13 +128,13 @@ def test_megabatch_multi_axis_grid_single_compile():
         Axis("sim.slots", (101,)),
     ])
     reset_dispatch_stats()
-    execute_points(points, backend="jax", jx_dispatch="megabatch")
+    execute_points(points, backend="jax")
     stats = dispatch_stats()
     assert stats["dispatches"] == 1
     assert stats["compiles"] == 1
     # warm re-run: same program, no new compile
     reset_dispatch_stats()
-    execute_points(points, backend="jax", jx_dispatch="megabatch")
+    execute_points(points, backend="jax")
     stats = dispatch_stats()
     assert stats["dispatches"] == 1
     assert stats["compiles"] == 0
@@ -140,8 +143,8 @@ def test_megabatch_multi_axis_grid_single_compile():
 def test_megabatch_mixed_topology_kinds_one_compile_per_bucket():
     """A grid mixing leaf_spine and fat_tree points (the topology-axis
     experiment shape) fuses into exactly one launch and one program
-    compile per topology-kind shape bucket, row-identical to the
-    per-group dispatch."""
+    compile per topology-kind shape bucket, row-identical to each point
+    run alone."""
     points = _grid_points(None, [
         Axis("scenario", ("bisection_multiplane", "bisection_fat_tree")),
         Axis("sim.routing", ("war", "ecmp")),
@@ -150,32 +153,32 @@ def test_megabatch_mixed_topology_kinds_one_compile_per_bucket():
     ])
     reset_dispatch_stats()
     with jax.enable_x64(True):
-        mega = execute_points(points, backend="jax",
-                              jx_dispatch="megabatch")
+        mega = execute_points(points, backend="jax")
     stats = dispatch_stats()
     assert stats["dispatches"] == 2, stats   # one per topology kind
     assert stats["compiles"] == 2, stats
-    with jax.enable_x64(True):
-        group = execute_points(points, backend="jax", jx_dispatch="group")
-    _assert_rows_identical(points, group, mega)
+    alone, _ = _run_both(points)
+    _assert_rows_identical(points, alone, mega)
 
 
 def test_jitted_rebuilds_on_device_set_change(monkeypatch):
-    """Regression: `_jitted` used to key its memo on `JxConfig` only, so
-    a pmap callable built for N host devices was silently reused after
-    the visible device set changed."""
-    from repro.scenarios import compile_scenario, get_scenario
+    """Regression: a jit memo keyed on `JxConfig` only would silently
+    reuse a program built for N devices after the visible device set
+    changed; `_jitted_mb` keys on the device fingerprint too."""
+    from repro.netsim.jx import megabatch
+    from repro.scenarios import get_scenario
 
     spec = get_scenario("flap_during_incast").with_sim(slots=50)
-    cfg = engine.JxConfig.from_sim(compile_scenario(spec).cfg, spec.topo)
-    fn_a = engine._jitted(cfg, batched=True, n_shards=1)
-    assert engine._jitted(cfg, batched=True, n_shards=1) is fn_a
+    cfg = megabatch._struct_cfg(compile_scenario(spec))
+    lanes = ((engine.ROUTE_PAIR, 1),)
+    fn_a = engine._jitted_mb(cfg, 1, lanes)
+    assert engine._jitted_mb(cfg, 1, lanes) is fn_a
     monkeypatch.setattr(engine, "_device_fingerprint",
                         lambda: (("cpu", 0), ("cpu", 1)))
-    fn_b = engine._jitted(cfg, batched=True, n_shards=1)
+    fn_b = engine._jitted_mb(cfg, 1, lanes)
     assert fn_b is not fn_a
     monkeypatch.undo()
-    assert engine._jitted(cfg, batched=True, n_shards=1) is fn_a
+    assert engine._jitted_mb(cfg, 1, lanes) is fn_a
 
 
 def test_stack_idx_covers_every_routing_nic():
@@ -201,7 +204,7 @@ def test_stack_idx_covers_every_routing_nic():
 def test_megabatch_registry_wide_row_identity(routing, nic):
     """Registry-wide: every scenario (mixed flow buckets, timelines,
     finite transfers) through one executor call per (routing, nic),
-    megabatch vs per-group.  Schedule scenarios pin their own horizon
+    against each point run alone.  Schedule scenarios pin their own horizon
     (the compiler rejects a sim too short for every training step), so
     they keep their registry slots instead of the 150-slot shrink."""
     from repro.scenarios import get_scenario
@@ -218,5 +221,5 @@ def test_megabatch_registry_wide_row_identity(routing, nic):
         Axis("sim.routing", (routing,)),
         Axis("sim.nic", (nic,)),
     ])
-    group, mega = _run_both(points)
-    _assert_rows_identical(points, group, mega)
+    alone, mega = _run_both(points)
+    _assert_rows_identical(points, alone, mega)

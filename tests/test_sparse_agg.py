@@ -137,3 +137,21 @@ def test_f32_carry_drift_vs_f64_bounded():
     if not np.isnan(f64.completion_tail):
         assert f32.completion_tail == pytest.approx(
             f64.completion_tail, rel=1e-3, abs=1e-6)
+
+
+def test_giga_point_plans_one_sparse_program():
+    """The 4,096-host, 102,400-flow registry point stays on the sparse
+    aggregation path and fuses into one program — one launch and one
+    compile.  Planned with `megabatch_programs`, never run."""
+    from repro.netsim.jx import megabatch
+    from repro.scenarios import compile_scenario
+
+    compiled = compile_scenario(
+        get_scenario("giga_fabric_storage").with_sim(backend="jax"))
+    assert compiled.spec.topo.n_hosts >= 4096
+    assert len(compiled.flows) >= 100_000
+    (fn, args), = megabatch.megabatch_programs([compiled], n_devices=1)
+    cfg = megabatch._struct_cfg(compiled)
+    assert cfg.agg_mode == "sparse" and not cfg.flow_chunk
+    # one lane: the 102,400 flows in their 131,072-flow bucket
+    assert args[2].src.shape == (1, 131_072)

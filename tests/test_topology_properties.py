@@ -5,11 +5,11 @@ satellite) over BOTH fabric kinds:
     pairs together, so `maxflow_matrix` stays symmetric under any fault
     schedule;
   * monotone non-increase — no fault may increase any pair's max-flow;
-  * capacity-proportional bisection after `random_fail` — the surviving
-    cross-cut max-flow brackets between the per-path survival law of
-    the fabric's hop count ((1-f)^2 for the 2-stage leaf-spine,
-    (1-f)^4 for 4-hop cross-pod fat-tree paths) and the raw capacity
-    fraction (1-f): the quantitative form of §6.4's claim that the
+  * capacity-proportional bisection after `random_fail` — the mean
+    surviving cross-cut max-flow over a batch of draws brackets between
+    the per-path survival law of the fabric's hop count ((1-f)^2 for
+    the 2-stage leaf-spine, (1-f)^4 for 4-hop cross-pod fat-tree paths)
+    and the raw capacity fraction (1-f): the quantitative form of §6.4's claim that the
     multiplane degrades capacity-proportionally while the hierarchy
     strands surviving capacity;
   * the fat-tree fault-timeline compiler matches the callback-driven
@@ -93,33 +93,48 @@ def test_maxflow_symmetric_and_monotone_under_faults(data, seed, n_faults):
         prev = mf
 
 
+# The cut ratio of one draw scatters around its mean: over 4,000 draws
+# its sd is 0.026-0.040 on the leaf-spine below and 0.059-0.106 on the
+# fat-tree (f = 0.05-0.2), and the leaf-spine's mean IS the lower law
+# (1-f)^2, so a per-draw bracket fails about one draw in 135.  The law
+# is held by the mean of DRAWS draws instead, within Z standard errors
+# (the batch's sample sd / sqrt(DRAWS)): a sound fabric misses by more
+# than 5 standard errors with probability under 3e-7 a check.
+DRAWS = 200
+Z = 5.0
+
+
 @given(kind=st.sampled_from(["leaf_spine", "fat_tree"]),
        seed=st.integers(0, 2 ** 16),
        frac=st.sampled_from([0.05, 0.1, 0.2]))
 @settings(**SETTINGS)
 def test_capacity_proportional_bisection_after_random_fail(kind, seed,
                                                           frac):
-    """Cross-cut max-flow after uniform random link failures brackets
-    between the hop-count survival law and raw capacity proportionality
-    (±10% for per-draw noise).  The fat-tree's 4-hop exponent IS the
-    hierarchy penalty the multiplane design deletes."""
+    """Cross-cut max-flow after uniform random link failures brackets,
+    in expectation, between the hop-count survival law and raw capacity
+    proportionality.  The fat-tree's 4-hop exponent IS the hierarchy
+    penalty the multiplane design deletes."""
     if kind == "leaf_spine":
-        t = LeafSpine(n_leaves=8, n_spines=16, hosts_per_leaf=2,
-                      n_planes=2)
+        pristine = LeafSpine(n_leaves=8, n_spines=16, hosts_per_leaf=2,
+                             n_planes=2)
         hops = 2
     else:
-        t = FatTree(n_pods=2, leaves_per_pod=4, n_aggs=8, n_cores=16,
-                    hosts_per_leaf=2, core_link_cap=4.0)
+        pristine = FatTree(n_pods=2, leaves_per_pod=4, n_aggs=8,
+                           n_cores=16, hosts_per_leaf=2, core_link_cap=4.0)
         hops = 4
-    L = t.n_leaves
-    left = np.arange(L // 2)
-    right = np.arange(L // 2, L)
-    base = maxflow_matrix(t)[np.ix_(left, right)].sum()
-    t.random_link_failures(np.random.default_rng(seed), frac)
-    after = maxflow_matrix(t)[np.ix_(left, right)].sum()
-    ratio = after / base
-    assert (1 - frac) ** hops - 0.10 <= ratio <= (1 - frac) + 0.10, \
-        (kind, frac, ratio)
+    L = pristine.n_leaves
+    cut = np.ix_(np.arange(L // 2), np.arange(L // 2, L))
+    base = maxflow_matrix(pristine)[cut].sum()
+    rng = np.random.default_rng(seed)
+    ratios = []
+    for _ in range(DRAWS):
+        t = pristine.copy()
+        t.random_link_failures(rng, frac)
+        ratios.append(maxflow_matrix(t)[cut].sum() / base)
+    mean = float(np.mean(ratios))
+    tol = Z * float(np.std(ratios, ddof=1)) / np.sqrt(DRAWS)
+    assert (1 - frac) ** hops - tol <= mean <= (1 - frac) + tol, \
+        (kind, frac, mean, tol)
 
 
 # ---------------------------------------------------------------------------

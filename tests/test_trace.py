@@ -89,19 +89,18 @@ def test_trace_off_no_capture_and_program_reuse():
     assert compile_scenario(points_off[0]).run().trace is None
 
     reset_dispatch_stats()
-    res_off = execute_points(points_off, backend="jax",
-                             jx_dispatch="megabatch")
+    res_off = execute_points(points_off, backend="jax")
     assert dispatch_stats() == {"dispatches": 1, "compiles": 1}
     assert all(np.isnan(m.bimodal_frac) for m in res_off)
     assert all(m.hft_transient_drops == -1 for m in res_off)
 
     reset_dispatch_stats()
-    execute_points(points_on, backend="jax", jx_dispatch="megabatch")
+    execute_points(points_on, backend="jax")
     assert dispatch_stats() == {"dispatches": 1, "compiles": 1}
 
     # back to trace-off: the original fused program serves the grid warm
     reset_dispatch_stats()
-    execute_points(points_off, backend="jax", jx_dispatch="megabatch")
+    execute_points(points_off, backend="jax")
     assert dispatch_stats() == {"dispatches": 1, "compiles": 0}
 
 
